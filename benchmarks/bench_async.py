@@ -42,9 +42,9 @@ OUT_PATH_P0 = os.path.join(os.path.dirname(__file__), "..", "results",
 
 # modest single-CPU scale; a hard 25% phase split gives sync and async the
 # IDENTICAL phase-0, so the comparison isolates the phase-1 mechanics.
-# Eval runs the jnp segment-op path: on a CPU container the Pallas kernel
-# is interpret-mode (slow python emulation) and eval cost is excluded from
-# the step-time metrics being compared anyway.
+# Eval runs the jnp segment-op path: off a TPU the Pallas kernel runs in
+# interpret mode (slow emulation) and eval cost is excluded from the
+# step-time metrics being compared anyway.
 BENCH_KW = dict(dataset="products-s", partition_method="ew", use_cbs=True,
                 use_gp=True, max_epochs=20, hidden_dim=64, batch_size=256,
                 fanouts=(5, 5), lr=3e-3, phase0_fraction=0.25, seed=0,
@@ -128,6 +128,8 @@ def bench_phase0() -> dict:
 
 
 def main() -> int:
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     bench_phase0()
 
     rows = []

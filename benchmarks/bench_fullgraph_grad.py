@@ -9,12 +9,11 @@ update) with the aggregation routed through the Pallas custom-VJP op
 the centralized (1-partition, Table IV) configuration and the partitioned
 fleet.
 
-On this CPU container the kernel path runs in Pallas INTERPRET mode, which
-executes the kernel body in Python — the recorded kernel/jnp ratio is a
+Off a TPU the kernel path runs in Pallas INTERPRET mode, which executes
+the kernel body as plain XLA ops — the recorded kernel/jnp ratio is then a
 correctness-witnessed stand-in, not a speedup claim.  On a TPU mesh:
 
-    PYTHONPATH=src python benchmarks/bench_fullgraph_grad.py \
-        --engine spmd --no-interpret
+    PYTHONPATH=src python benchmarks/bench_fullgraph_grad.py --engine spmd
 
 Emits ``results/BENCH_fullgraph_train.json`` with per-config step times,
 the kernel/jnp ratios, and trace evidence that BOTH the forward and the
@@ -80,12 +79,11 @@ def run_parts(args, parts: int) -> list[dict]:
                                             args.hidden)
     rows = []
     for path, use_pallas in (("kernel", True), ("jnp", False)):
-        cfg = EngineConfig(mode=args.engine, use_pallas_agg=use_pallas,
-                           interpret=not args.no_interpret)
+        cfg = EngineConfig(mode=args.engine, use_pallas_agg=use_pallas)
         eng = SPMDEngine(model, loss_fn, opt, pg, GPHyperParams(), cfg)
         before = sa.pallas_call_count()
         row = {"dataset": args.dataset, "parts": parts, "path": path,
-               "engine": eng.mode, "interpret": not args.no_interpret,
+               "engine": eng.mode, "interpret": sa.default_interpret(),
                "num_nodes": g.num_nodes, "num_edges": g.num_edges,
                "max_nodes": pg.max_nodes,
                "halo_bytes_per_layer": pg.halo_bytes_per_layer}
@@ -100,32 +98,30 @@ def run_parts(args, parts: int) -> list[dict]:
 
 
 def main() -> int:
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="products-s")
     ap.add_argument("--parts", type=int, nargs="*", default=[1, 4],
                     help="1 = the centralized Table IV configuration")
     ap.add_argument("--engine", default="stacked",
                     choices=("stacked", "spmd"))
-    ap.add_argument("--no-interpret", action="store_true",
-                    help="compiled Pallas (real TPU mesh)")
     ap.add_argument("--hidden", type=int, default=64)
     ap.add_argument("--repeats", type=int, default=5)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
-    if args.engine == "spmd":
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                f"{flags} --xla_force_host_platform_device_count="
-                f"{max(args.parts)}").strip()
-
     rows = []
     for parts in args.parts:
         rows.extend(run_parts(args, parts))
 
+    import jax
+
+    from repro.kernels.segment_agg import default_interpret
+
     out = {"dataset": args.dataset, "engine": args.engine,
-           "interpret": not args.no_interpret, "configs": rows}
+           "platform": jax.default_backend(),
+           "interpret": default_interpret(), "configs": rows}
     for parts in args.parts:
         ker = next(r for r in rows
                    if r["parts"] == parts and r["path"] == "kernel")

@@ -66,6 +66,8 @@ def run_parts(args, parts: int) -> list[dict]:
 
 
 def main() -> int:
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="products-s")
     ap.add_argument("--parts", type=int, nargs="*", default=[4, 8])

@@ -14,8 +14,11 @@ hide, so the measured win is the split layout's structural work reduction
 (halo rows here are 70-85% of the padded local space).  On a real mesh the
 exchange additionally overlaps the interior work:
 
-    PYTHONPATH=src python benchmarks/bench_halo_overlap.py \
-        --engine spmd --no-interpret
+    PYTHONPATH=src python benchmarks/bench_halo_overlap.py --engine spmd
+
+(``--engine spmd`` needs one device per partition; on a CPU host set
+``XLA_FLAGS=--xla_force_host_platform_device_count=N`` before starting.
+The Pallas kernel runs compiled on a TPU and interpreted elsewhere.)
 
 Emits ``results/BENCH_halo_overlap.json`` with per-config forward step
 times, overlap/sync ratios, and the bytes each exchange moves (real halo
@@ -65,14 +68,13 @@ def make_forward_step(eng, params):
     if eng.mode == "spmd":
         from jax.sharding import PartitionSpec as P
 
-        from repro.engine.compat import shard_map_compat
-
         def shard_fn(prm, shard_s):
             sh = jax.tree.map(lambda x: x[0], shard_s)
             return eng.fwd(prm, sh)[None]
 
-        fn = shard_map_compat(shard_fn, eng._mesh,
-                              in_specs=(P(), P(AXIS)), out_specs=P(AXIS))
+        fn = jax.shard_map(shard_fn, mesh=eng._mesh,
+                           in_specs=(P(), P(AXIS)), out_specs=P(AXIS),
+                           check_vma=False)
     else:
         def fn(prm, shards):
             return jax.vmap(eng.fwd, axis_name=AXIS,
@@ -101,17 +103,18 @@ def time_step(step, repeats: int) -> dict:
 def run_parts(args, parts: int) -> list[dict]:
     from repro.core import GPHyperParams
     from repro.engine import EngineConfig, SPMDEngine
+    from repro.kernels.segment_agg import default_interpret
 
     g, pg, model, loss_fn, opt = build_case(args.dataset, parts, args.seed)
     rows = []
     for mode, over_kw in MODES.items():
         cfg = EngineConfig(mode=args.engine, use_pallas_agg=args.pallas,
-                           interpret=not args.no_interpret, **over_kw)
+                           **over_kw)
         eng = SPMDEngine(model, loss_fn, opt, pg, GPHyperParams(), cfg)
         params = model.init(args.seed)
         row = {"dataset": args.dataset, "parts": parts, "mode": mode,
                "engine": eng.mode, "pallas_agg": args.pallas,
-               "interpret": not args.no_interpret,
+               "interpret": default_interpret(),
                "max_nodes": pg.max_nodes, "own_cap": pg.own_cap,
                "n_int": pg.n_int.tolist(),
                "n_boundary": pg.n_boundary.tolist(),
@@ -125,6 +128,8 @@ def run_parts(args, parts: int) -> list[dict]:
 
 
 def main() -> int:
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="products-s")
     ap.add_argument("--parts", type=int, nargs="*", default=[4, 8])
@@ -132,8 +137,6 @@ def main() -> int:
                     choices=("stacked", "spmd"),
                     help="stacked single-device fallback (default) or "
                          "shard_map over a partition mesh")
-    ap.add_argument("--no-interpret", action="store_true",
-                    help="compiled Pallas (real TPU mesh)")
     ap.add_argument("--pallas", action="store_true",
                     help="route aggregation through the Pallas kernel "
                          "(interpret mode is slow on CPU; default is the "
@@ -142,19 +145,15 @@ def main() -> int:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
-    if args.engine == "spmd":
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                f"{flags} --xla_force_host_platform_device_count="
-                f"{max(args.parts)}").strip()
+    from repro.kernels.segment_agg import default_interpret
 
     rows = []
     for parts in args.parts:
         rows.extend(run_parts(args, parts))
 
     out = {"dataset": args.dataset, "engine": args.engine,
-           "interpret": not args.no_interpret, "configs": rows}
+           "platform": jax.default_backend(),
+           "interpret": default_interpret(), "configs": rows}
     ok = True
     for parts in args.parts:
         sync = next(r for r in rows

@@ -58,6 +58,8 @@ def build(args):
 
 
 def main() -> int:
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="products-s")
     ap.add_argument("--parts", type=int, default=4)

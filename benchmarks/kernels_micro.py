@@ -1,6 +1,6 @@
-"""Kernel microbenchmarks: us_per_call of the jnp reference path on CPU, and
-allclose drift vs the Pallas kernel (interpret mode — TPU timings are the
-dry-run's job; this guards correctness + tracks the oracle's CPU cost)."""
+"""Kernel microbenchmarks: us_per_call of the jnp reference path, and
+allclose drift vs the Pallas kernel (interpreted off a TPU; this guards
+correctness + tracks the oracle's cost, not a device time)."""
 from __future__ import annotations
 
 import time

@@ -27,6 +27,8 @@ MODULES = {
 
 
 def main() -> None:
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None, choices=list(MODULES))
     args = ap.parse_args()

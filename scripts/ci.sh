@@ -50,8 +50,9 @@ export PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH}
 # ONE shared persistent XLA compile cache for the whole run: the in-process
 # tests pick it up from the environment, the subprocess scripts point at the
 # same directory via tests/_jax_cache.py, so every stage reuses every other
-# stage's lowered executables across reruns
-export JAX_COMPILATION_CACHE_DIR="$PWD/.jax_cache"
+# stage's lowered executables across reruns.  A directory the caller set is
+# kept; otherwise the checkout's .jax_cache
+export JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-$PWD/.jax_cache}"
 export JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS=0.5
 
 mode=${1:-tier1}

@@ -23,9 +23,14 @@ Three execution modes share one per-shard program:
               oracle for tests/test_engine_parity.py and the numerically
               faithful descendant of the original per-partition driver.
 
+Every ``jax.shard_map`` runs with ``check_vma=False``: the engine's
+phase-0 outputs are replicated *by construction* (identical reductions ->
+identical updates), which the checker cannot prove through ``lax.scan``.
+
 GraphSAGE's full-graph mean aggregation routes through the Pallas
-``segment_agg`` kernel (``use_pallas_agg=True``) with the jnp segment-op
-reference as interpret-mode fallback.
+``segment_agg`` kernel (``use_pallas_agg=True``, compiled on a TPU and
+interpreted elsewhere) or, with ``use_pallas_agg=False``, through the jnp
+segment-op reference.
 """
 from __future__ import annotations
 
@@ -35,7 +40,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.gp.trainer import (GPHyperParams, GRAD_COMPRESS_MODES,
                                grad_topk_size, make_bucketed_reduce_shard,
@@ -56,7 +61,6 @@ from ..graph.featstore import (assemble_features, check_feat_budget,
                                feat_peak_bytes, reconstruct_features)
 from ..train.metrics import f1_scores_jnp
 from ..train.optim import apply_updates
-from .compat import shard_map_compat
 from .stacking import (build_stacked_feat_store, build_stacked_halo_cache,
                        build_stacked_halo_residual,
                        build_stacked_split_vjp_blocks,
@@ -71,7 +75,6 @@ AXIS = "parts"
 class EngineConfig:
     mode: str = "auto"              # auto | spmd | stacked | sequential
     use_pallas_agg: bool = True     # route eval aggregation through Pallas
-    interpret: bool = True          # Pallas interpret mode (CPU container)
     dtype: Any = jnp.float32        # float dtype of graph features
     # boundary/interior split forward: overlap the halo exchange with
     # interior aggregation + the self-term matmul, and restrict dense
@@ -241,7 +244,7 @@ class SPMDEngine:
         self._fs = None
         self._cold_host = None
         self._streamer = None
-        self.shards = {
+        shards = {
             "send_idx": jnp.asarray(pg.send_idx),
             "send_mask": jnp.asarray(pg.send_mask, f),
             "recv_pos": jnp.asarray(pg.recv_pos),
@@ -249,10 +252,10 @@ class SPMDEngine:
         if self.feat_store:
             entries, self._fs = build_stacked_feat_store(
                 pg, config.hot_frac, config.hot_policy, f)
-            self.shards.update(entries)
+            shards.update(entries)
             self._cold_host = self._fs.cold
         else:
-            self.shards["features"] = jnp.asarray(pg.features, f)
+            shards["features"] = jnp.asarray(pg.features, f)
         check_feat_budget(config.feat_budget_mb, self._feat_peak_bytes(pg),
                           context=f"mode={self.mode}")
         def _as_blk(d: dict) -> dict:
@@ -264,13 +267,13 @@ class SPMDEngine:
         if config.overlap_halo:
             # split forward state: the per-partition interior row count plus
             # ONE aggregation backend's structures (the other is never read)
-            self.shards["n_int"] = jnp.asarray(pg.n_int, jnp.int32)
+            shards["n_int"] = jnp.asarray(pg.n_int, jnp.int32)
             if config.use_pallas_agg:
                 bi, bb = build_stacked_split_vjp_blocks(pg)
-                self.shards["blk_int"] = _as_blk(bi)
-                self.shards["blk_bnd"] = _as_blk(bb)
+                shards["blk_int"] = _as_blk(bi)
+                shards["blk_bnd"] = _as_blk(bb)
             else:
-                self.shards.update({
+                shards.update({
                     "int_src": jnp.asarray(pg.int_src),
                     "int_dst": jnp.asarray(pg.int_dst),
                     "bnd_src": jnp.asarray(pg.bnd_src),
@@ -278,19 +281,26 @@ class SPMDEngine:
                     "deg": jnp.asarray(pg.deg, f),
                 })
         else:
-            self.shards.update({
+            shards.update({
                 "edge_src": jnp.asarray(pg.edge_src),
                 "edge_dst": jnp.asarray(pg.edge_dst),
                 "edge_mask": jnp.asarray(pg.edge_mask, f),
             })
             if config.use_pallas_agg:
-                self.shards["blk"] = _as_blk(build_stacked_vjp_blocks(pg))
-        self.labels = jnp.asarray(pg.labels)
-        self.masks = {
-            "train": jnp.asarray(pg.train_mask),
-            "val": jnp.asarray(pg.val_mask),
-            "test": jnp.asarray(pg.test_mask),
-        }
+                shards["blk"] = _as_blk(build_stacked_vjp_blocks(pg))
+        self._mesh = None
+        if self.mode == "spmd":
+            from ..launch.mesh import make_partition_mesh
+            self._mesh = make_partition_mesh(self.num_parts, AXIS)
+        # the resident (P, ...) graph data; on the mesh each device holds
+        # only its own partition's slice
+        resident = {"shards": shards, "labels": pg.labels,
+                    "masks": {"train": pg.train_mask, "val": pg.val_mask,
+                              "test": pg.test_mask}}
+        where = (NamedSharding(self._mesh, P(AXIS)) if self._mesh is not None
+                 else None)
+        self._resident = jax.tree.map(
+            lambda x: jax.device_put(jnp.asarray(x), where), resident)
 
         meta = {"max_nodes": pg.max_nodes, "own_cap": pg.own_cap}
         self._fwd_meta = meta
@@ -300,13 +310,13 @@ class SPMDEngine:
                     "halo_cache and overlap_halo are alternative exchange "
                     "optimisations: the cache removes the very exchange the "
                     "overlap would hide — pick one")
-            aggs = (make_pallas_split_agg(pg.own_cap, interpret=config.interpret)
+            aggs = (make_pallas_split_agg(pg.own_cap)
                     if config.use_pallas_agg else make_ref_split_agg(pg.own_cap))
             self.fwd = make_overlap_forward(
                 model, meta, axis_name=AXIS, agg_interior=aggs[0],
                 agg_boundary=aggs[1], ring_chunks=config.ring_chunks)
         else:
-            agg = (make_pallas_mean_agg(pg.max_nodes, interpret=config.interpret)
+            agg = (make_pallas_mean_agg(pg.max_nodes)
                    if config.use_pallas_agg else make_ref_mean_agg(pg.max_nodes))
             self._mean_agg = agg
             self.fwd = make_distributed_forward(model, meta, axis_name=AXIS,
@@ -353,14 +363,24 @@ class SPMDEngine:
         self._sampler_gen = 0
         self.last_eval_seconds = 0.0   # execution time of the latest
                                        # separately-compiled evaluate() call
-        self._mesh = None
-        if self.mode == "spmd":
-            from ..launch.mesh import make_partition_mesh
-            self._mesh = make_partition_mesh(self.num_parts, AXIS)
         self._cache: dict = {}
         self.compile_count = 0
+        self.compile_seconds = 0.0     # trace + lower + compile, all calls
 
     # ------------------------------------------------------------ plumbing
+    @property
+    def shards(self) -> dict:
+        """Per-partition graph shards, every leaf stacked ``(P, ...)``."""
+        return self._resident["shards"]
+
+    @property
+    def labels(self):
+        return self._resident["labels"]
+
+    @property
+    def masks(self) -> dict:
+        return self._resident["masks"]
+
     def _shape_key(self, name: str, args) -> tuple:
         # shardings are part of the key: an AOT executable is specialised to
         # its input shardings, and epoch 2's params arrive sharded over the
@@ -377,12 +397,32 @@ class SPMDEngine:
         """AOT lower+compile once per input-shape signature, so epoch timing
         in the pipeline never includes XLA compilation.  ``compile_count``
         exposes the misses: identically shaped/sharded fresh inputs must
-        reuse the executable (locked by a tier-1 regression test)."""
+        reuse the executable (locked by a tier-1 regression test).
+
+        The resident graph data enters every executable as its leading
+        ARGUMENT, never as a closed-over constant, which would be baked into
+        each program (one device copy per executable, and on a mesh a full
+        copy on every device).  ``fn`` reads it through ``shards`` /
+        ``labels`` / ``masks``, bound to that argument while it traces."""
         key = self._shape_key(name, args)
         if key not in self._cache:
+            import time
+
             self.compile_count += 1
-            self._cache[key] = jax.jit(fn).lower(*args).compile()
-        return self._cache[key]
+            t0 = time.perf_counter()
+
+            def with_resident(resident, *a):
+                saved, self._resident = self._resident, resident
+                try:
+                    return fn(*a)
+                finally:
+                    self._resident = saved
+
+            self._cache[key] = jax.jit(with_resident).lower(
+                self._resident, *args).compile()
+            self.compile_seconds += time.perf_counter() - t0
+        exe = self._cache[key]
+        return lambda *a: exe(self._resident, *a)
 
     def _micro_of(self, preds, labels, mask):
         lab = jnp.where(mask, labels, -1)
@@ -598,14 +638,14 @@ class SPMDEngine:
             return head + ((jax.tree.map(lambda x: x[None], nr),)
                            if comp else ())
 
-        fn = shard_map_compat(
-            shard_fn, self._mesh,
+        fn = jax.shard_map(
+            shard_fn, mesh=self._mesh,
             in_specs=(P(AXIS) if per_partition_params else P(),
                       P(AXIS), P(AXIS), P(AXIS), P(AXIS))
                      + ((P(AXIS),) if comp else ())
                      + (P(AXIS),) * len(fs),
             out_specs=(P(AXIS), P(AXIS), P(AXIS))
-                      + ((P(AXIS),) if comp else ()))
+                      + ((P(AXIS),) if comp else ()), check_vma=False)
         args = (params, cache, self.shards, self.labels, self.masks[split])
         if comp:
             args = args + (residual,)
@@ -638,12 +678,12 @@ class SPMDEngine:
             micro = self._micro_of(preds, labels_s[0], mask_s[0])
             return micro[None], preds[None], jax.tree.map(lambda x: x[None], nr)
 
-        fn = shard_map_compat(
-            shard_fn, self._mesh,
+        fn = jax.shard_map(
+            shard_fn, mesh=self._mesh,
             in_specs=(P(AXIS) if per_partition_params else P(),
                       P(AXIS), P(AXIS), P(AXIS), P(AXIS))
                      + (P(AXIS),) * len(fs),
-            out_specs=(P(AXIS), P(AXIS), P(AXIS)))
+            out_specs=(P(AXIS), P(AXIS), P(AXIS)), check_vma=False)
         return fn(params, residual, self.shards, self.labels,
                   self.masks[split], *fs)
 
@@ -672,20 +712,31 @@ class SPMDEngine:
         if self.grad_compress == "topk":
             return make_topk_reduce_stacked(num_parts,
                                             self.config.grad_topk_frac)
-        # the all-reduce: stacked-axis mean == lax.pmean on the mesh
+        # the all-reduce: the same stacked-axis mean the mesh computes
+        # after its all_gather
         return lambda grads: jax.tree.map(
             lambda g: jnp.sum(g, axis=0) / num_parts, grads)
 
     def _grad_reduce_shard(self):
-        """Per-shard (collective) reducer for grad_compress; mode "none"
-        returns None — the caller keeps its existing spelling untouched."""
+        """Per-shard (collective) reducer for grad_compress.  The plain mean
+        is spelled ``all_gather`` + a local stack-axis sum: pure data
+        movement followed by the stacked mode's own deterministic
+        reduction, so a mesh run is bitwise the stacked one (a ``pmean``'s
+        reduction order is the collective implementation's choice)."""
+        num_parts = self.num_parts
         if self.grad_compress == "bucketed":
             return make_bucketed_reduce_shard(
-                self.num_parts, AXIS, self.config.grad_bucket_kb * 1024)
+                num_parts, AXIS, self.config.grad_bucket_kb * 1024)
         if self.grad_compress == "topk":
-            return make_topk_reduce_shard(self.num_parts, AXIS,
+            return make_topk_reduce_shard(num_parts, AXIS,
                                           self.config.grad_topk_frac)
-        return None
+
+        def mean(grads):
+            g_all = jax.lax.all_gather(grads, AXIS)          # (P, ...)
+            return jax.tree.map(lambda g: jnp.sum(g, axis=0) / num_parts,
+                                g_all)
+
+        return mean
 
     def _phase0_stacked(self, params, opt_state, batches, grad_res=None):
         reduce = self._grad_reduce_stacked()
@@ -755,8 +806,7 @@ class SPMDEngine:
             def one(carry, _):
                 p, o = carry
                 loss, grads = jax.value_and_grad(self._fg_loss)(p, batch)
-                grads = (jax.lax.pmean(grads, AXIS) if g_reduce is None
-                         else g_reduce(grads))
+                grads = g_reduce(grads)
                 updates, o = self.optimizer.update(grads, o, p)
                 return (apply_updates(p, updates), o), loss
 
@@ -764,10 +814,10 @@ class SPMDEngine:
                 one, (params, opt_state), None, length=iters)
             return params, opt_state, losses[:, None]
 
-        fn = shard_map_compat(
-            shard_fn, self._mesh,
+        fn = jax.shard_map(
+            shard_fn, mesh=self._mesh,
             in_specs=(P(), P(), P(AXIS), P(AXIS), P(AXIS)),
-            out_specs=(P(), P(), P(None, AXIS)))
+            out_specs=(P(), P(), P(None, AXIS)), check_vma=False)
         return fn(params, opt_state, self.shards, self.labels,
                   self.masks["train"])
 
@@ -796,7 +846,6 @@ class SPMDEngine:
         fused eval's plane); they are inputs only, never returned.
         """
         ds = self._device_sampler
-        num_parts = self.num_parts
         comp = self.halo_compress != "none"
         topk = self.grad_compress == "topk"
         fs_on = self.feat_store
@@ -834,12 +883,7 @@ class SPMDEngine:
                     p, o = carry
                     batch = ds.make_batch(k_i, n_i, v_i, **ck)
                     loss, grads = jax.value_and_grad(self.loss_fn)(p, batch)
-                    if g_reduce is not None:              # bucketed psum
-                        grads = g_reduce(grads)
-                    else:
-                        g_all = jax.lax.all_gather(grads, AXIS)   # (P, ...)
-                        grads = jax.tree.map(
-                            lambda g: jnp.sum(g, axis=0) / num_parts, g_all)
+                    grads = g_reduce(grads)
                     updates, o = self.optimizer.update(grads, o, p)
                     return (apply_updates(p, updates), o), loss
 
@@ -917,12 +961,13 @@ class SPMDEngine:
             return head + tuple(jax.tree.map(lambda x: x[None], c)
                                 for c in out[4:])
 
-        fn = shard_map_compat(
-            shard_fn, self._mesh,
+        fn = jax.shard_map(
+            shard_fn, mesh=self._mesh,
             in_specs=(P(), P(), P(AXIS), P(AXIS), P(AXIS), P(AXIS),
                       P(AXIS), P(AXIS), P(AXIS)) + (P(AXIS),) * n_st
                      + ((P(), P(AXIS)) if fs else ()),
-            out_specs=(P(), P(), P(None, AXIS), P(AXIS)) + (P(AXIS),) * n_st)
+            out_specs=(P(), P(), P(None, AXIS), P(AXIS)) + (P(AXIS),) * n_st,
+            check_vma=False)
         args = (params, opt_state, keys, ds.logp, ds.train_idx, ds.k,
                 self.shards, self.labels, self.masks["val"]) \
             + tuple(state) + tuple(fs)
@@ -1004,10 +1049,10 @@ class SPMDEngine:
                     one, (params, opt_state, res_s[0]), b)
                 return params, opt_state, losses[:, None], res[None]
 
-            fn = shard_map_compat(
-                shard_fn_t, self._mesh,
+            fn = jax.shard_map(
+                shard_fn_t, mesh=self._mesh,
                 in_specs=(P(), P(), P(None, AXIS), P(AXIS)),
-                out_specs=(P(), P(), P(None, AXIS), P(AXIS)))
+                out_specs=(P(), P(), P(None, AXIS), P(AXIS)), check_vma=False)
             return fn(params, opt_state, batches, grad_res)
 
         # like make_generalize_step(axis_names=(AXIS,)) but reporting the
@@ -1015,8 +1060,7 @@ class SPMDEngine:
         # the engine's (I, P) loss matrix must stay per-host for parity
         def gen_step(params, opt_state, batch):
             loss, grads = jax.value_and_grad(self.loss_fn)(params, batch)
-            grads = (jax.lax.pmean(grads, AXIS) if g_reduce is None
-                     else g_reduce(grads))
+            grads = g_reduce(grads)
             updates, opt_state = self.optimizer.update(grads, opt_state, params)
             return apply_updates(params, updates), opt_state, loss
 
@@ -1031,10 +1075,10 @@ class SPMDEngine:
             (params, opt_state), losses = jax.lax.scan(one, (params, opt_state), b)
             return params, opt_state, losses[:, None]
 
-        fn = shard_map_compat(
-            shard_fn, self._mesh,
+        fn = jax.shard_map(
+            shard_fn, mesh=self._mesh,
             in_specs=(P(), P(), P(None, AXIS)),
-            out_specs=(P(), P(), P(None, AXIS)))
+            out_specs=(P(), P(), P(None, AXIS)), check_vma=False)
         return fn(params, opt_state, batches)
 
     def _phase1_spmd(self, pparams, popt, batches, global_params, budgets):
@@ -1060,10 +1104,10 @@ class SPMDEngine:
                     jax.tree.map(lambda x: x[None], po),
                     losses[:, None])
 
-        fn = shard_map_compat(
-            shard_fn, self._mesh,
+        fn = jax.shard_map(
+            shard_fn, mesh=self._mesh,
             in_specs=(P(AXIS), P(AXIS), P(None, AXIS), P(), P(AXIS)),
-            out_specs=(P(AXIS), P(AXIS), P(None, AXIS)))
+            out_specs=(P(AXIS), P(AXIS), P(None, AXIS)), check_vma=False)
         return fn(pparams, popt, batches, global_params, budgets)
 
     def _phase1_async_spmd(self, pparams, popt, keys, budgets, global_params,
@@ -1081,11 +1125,11 @@ class SPMDEngine:
                     jax.tree.map(lambda x: x[None], po),
                     losses[:, None])
 
-        fn = shard_map_compat(
-            shard_fn, self._mesh,
+        fn = jax.shard_map(
+            shard_fn, mesh=self._mesh,
             in_specs=(P(AXIS), P(AXIS), P(AXIS), P(AXIS), P(),
                       P(AXIS), P(AXIS), P(AXIS)) + (P(),) * len(fs),
-            out_specs=(P(AXIS), P(AXIS), P(None, AXIS)))
+            out_specs=(P(AXIS), P(AXIS), P(None, AXIS)), check_vma=False)
         return fn(pparams, popt, keys, budgets, global_params,
                   ds.logp, ds.train_idx, ds.k, *fs)
 
@@ -1100,11 +1144,11 @@ class SPMDEngine:
             micro = self._micro_of(preds, labels_s[0], mask_s[0])
             return micro[None], preds[None]
 
-        fn = shard_map_compat(
-            shard_fn, self._mesh,
+        fn = jax.shard_map(
+            shard_fn, mesh=self._mesh,
             in_specs=(P(AXIS) if per_partition_params else P(),
                       P(AXIS), P(AXIS), P(AXIS)) + (P(AXIS),) * len(fs),
-            out_specs=(P(AXIS), P(AXIS)))
+            out_specs=(P(AXIS), P(AXIS)), check_vma=False)
         return fn(params, self.shards, self.labels, self.masks[split], *fs)
 
     # ------------------------------------------------------- public surface
@@ -1440,9 +1484,9 @@ class SPMDEngine:
             out_specs = {"layers": tuple(P(AXIS) for _ in range(L)),
                          "logits": P(AXIS),
                          "cache": {f"h{i}": P(AXIS) for i in range(L)}}
-            impl = shard_map_compat(shard_fn, self._mesh,
-                                    in_specs=(P(), P(AXIS)),
-                                    out_specs=out_specs)
+            impl = jax.shard_map(shard_fn, mesh=self._mesh,
+                                 in_specs=(P(), P(AXIS)),
+                                 out_specs=out_specs, check_vma=False)
         else:
             impl = jax.vmap(fwd_e, axis_name=AXIS, in_axes=(None, 0))
         fn = self._compiled("export_serving", impl, params, shards)

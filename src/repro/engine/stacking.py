@@ -4,10 +4,10 @@ blocked-CSR aggregation structure (and per-epoch minibatches) into uniform
 
 The Pallas ``segment_agg`` kernel needs a static block layout; partitions
 have ragged edge counts, so each partition's :class:`EdgeBlocks` is padded to
-the fleet-wide maximum ``(num_blocks, edges_per_block)``.  Padding edges
-carry ``mask == 0`` and source id 0, so they gather a real row but contribute
-nothing to the reduction — the same trick the kernel already uses for
-intra-block padding.
+the fleet-wide maximum ``(num_chunks, num_blocks)``.  Padding edges carry
+``mask == 0`` and source id 0, so they gather a real row but contribute
+nothing to the reduction — the same trick the kernel already uses for the
+tail of each block's last chunk.
 """
 from __future__ import annotations
 
@@ -88,13 +88,15 @@ def build_stacked_halo_residual(pg: PartitionedGraph,
 
 @dataclass(frozen=True)
 class StackedBlocks:
-    """Per-partition blocked CSR, padded to common shapes (leading axis P)."""
+    """Per-partition ragged blocked CSR, padded to common shapes (leading
+    axis P)."""
 
     num_blocks: int            # nb (common across partitions)
-    edges_per_block: int       # BE (fleet-wide max, multiple of BEC)
-    src: np.ndarray            # (P, nb, BE) int32 local source ids, pad -> 0
-    local_dst: np.ndarray      # (P, nb, BE) int32 in [0, BN)
-    mask: np.ndarray           # (P, nb, BE) float32
+    num_chunks: int            # T (fleet-wide max)
+    src: np.ndarray            # (P, T, BEC) int32 local source ids, pad -> 0
+    local_dst: np.ndarray      # (P, T, BEC) int32 in [0, BN)
+    mask: np.ndarray           # (P, T, BEC) float32
+    chunk_block: np.ndarray    # (P, T) int32 node block of each chunk
     deg: np.ndarray            # (P, nb, BN) float32 (>=1 where real)
 
 
@@ -111,22 +113,30 @@ def _local_csr(pg: PartitionedGraph, p: int) -> tuple[np.ndarray, np.ndarray]:
 
 
 def _stack_blocks(per_part, num_parts: int, bn: int) -> StackedBlocks:
-    """Pad a list of per-partition EdgeBlocks to fleet-common shapes
-    (at least one block so an all-empty fleet still yields a valid grid)."""
-    nb = max(1, max(b.num_blocks for b in per_part))
-    be = max(b.edges_per_block for b in per_part)
+    """Pad a list of per-partition EdgeBlocks to fleet-common shapes.  A
+    partition with fewer node blocks gets one all-pad chunk per missing
+    block (every block must own a chunk), and the chunk axis is padded with
+    all-pad chunks reducing into the last block, keeping the chunk->block
+    map sorted."""
+    nb = max(b.num_blocks for b in per_part)
+    T = max(b.num_chunks + nb - b.num_blocks for b in per_part)
     P = num_parts
-    src = np.zeros((P, nb, be), dtype=np.int32)
-    ldst = np.zeros((P, nb, be), dtype=np.int32)
-    mask = np.zeros((P, nb, be), dtype=np.float32)
+    bec = per_part[0].src.shape[-1]
+    src = np.zeros((P, T, bec), dtype=np.int32)
+    ldst = np.zeros((P, T, bec), dtype=np.int32)
+    mask = np.zeros((P, T, bec), dtype=np.float32)
+    blk = np.full((P, T), nb - 1, dtype=np.int32)
     deg = np.ones((P, nb, bn), dtype=np.float32)
     for p, b in enumerate(per_part):
-        src[p, : b.num_blocks, : b.edges_per_block] = b.src
-        ldst[p, : b.num_blocks, : b.edges_per_block] = b.local_dst
-        mask[p, : b.num_blocks, : b.edges_per_block] = b.mask
+        t = b.num_chunks
+        src[p, :t] = b.src
+        ldst[p, :t] = b.local_dst
+        mask[p, :t] = b.mask
+        blk[p, :t] = b.chunk_block
+        blk[p, t: t + nb - b.num_blocks] = np.arange(b.num_blocks, nb)
         deg[p, : b.num_blocks] = b.deg
-    return StackedBlocks(num_blocks=nb, edges_per_block=be,
-                         src=src, local_dst=ldst, mask=mask, deg=deg)
+    return StackedBlocks(num_blocks=nb, num_chunks=T, src=src,
+                         local_dst=ldst, mask=mask, chunk_block=blk, deg=deg)
 
 
 def _sub_csr(src: np.ndarray, dst: np.ndarray, mask: np.ndarray,
@@ -147,8 +157,10 @@ def _stack_vjp_dict(fwd_list, bwd_list, num_parts: int, bn: int) -> dict:
     ``segment_mean_op`` blocks dict, each side padded fleet-wide."""
     f = _stack_blocks(fwd_list, num_parts, bn)
     b = _stack_blocks(bwd_list, num_parts, bn)
-    return {"src": f.src, "dst": f.local_dst, "mask": f.mask, "deg": f.deg,
-            "t_src": b.src, "t_dst": b.local_dst, "t_mask": b.mask}
+    return {"src": f.src, "dst": f.local_dst, "mask": f.mask,
+            "blk": f.chunk_block, "deg": f.deg,
+            "t_src": b.src, "t_dst": b.local_dst, "t_mask": b.mask,
+            "t_blk": b.chunk_block}
 
 
 def build_stacked_vjp_blocks(pg: PartitionedGraph, bn: int = BN,

@@ -459,8 +459,8 @@ def halo_refresh_plan(age: int, refresh_every: int, cv: bool,
 
 def make_ref_mean_agg(max_nodes: int):
     """jnp segment-op mean aggregation over a shard's local edge list — the
-    interpret-mode / differentiable fallback (same math as kernels/ref.py,
-    specialised to the padded shard layout)."""
+    reference backend (same math as kernels/ref.py, specialised to the
+    padded shard layout)."""
 
     def mean_agg(h, shard):
         msg = h[shard["edge_src"]] * shard["edge_mask"][:, None].astype(h.dtype)
@@ -499,7 +499,7 @@ def make_ref_split_agg(own_cap: int):
     return agg_interior, agg_boundary
 
 
-def make_pallas_mean_agg(max_nodes: int, *, interpret: bool = True):
+def make_pallas_mean_agg(max_nodes: int):
     """Pallas-kernel mean aggregation: the GNN hot-spot on the MXU.
 
     Reads the paired forward/transpose blocked-CSR structure
@@ -512,13 +512,13 @@ def make_pallas_mean_agg(max_nodes: int, *, interpret: bool = True):
     from ..kernels.ops import segment_mean_op
 
     def mean_agg(h, shard):
-        return segment_mean_op(h, shard["blk"], num_rows=max_nodes,
-                               interpret=interpret).astype(h.dtype)
+        return segment_mean_op(h, shard["blk"],
+                               num_rows=max_nodes).astype(h.dtype)
 
     return mean_agg
 
 
-def make_pallas_split_agg(own_cap: int, *, interpret: bool = True):
+def make_pallas_split_agg(own_cap: int):
     """Pallas interior/boundary aggregation pair for the overlapped forward.
 
     Each half's blocked structure covers only its own row range — interior
@@ -534,12 +534,11 @@ def make_pallas_split_agg(own_cap: int, *, interpret: bool = True):
 
     def agg_interior(h, shard):
         return segment_mean_op(h, shard["blk_int"], num_rows=own_cap,
-                               row_base=0, interpret=interpret).astype(h.dtype)
+                               row_base=0).astype(h.dtype)
 
     def agg_boundary(h, shard):
         return segment_mean_op(h, shard["blk_bnd"], num_rows=own_cap,
-                               row_base=shard["n_int"],
-                               interpret=interpret).astype(h.dtype)
+                               row_base=shard["n_int"]).astype(h.dtype)
 
     return agg_interior, agg_boundary
 

@@ -124,7 +124,7 @@ class GraphSAGE:
     @staticmethod
     def neighbor_mean(x: jnp.ndarray, *, axis: int | None = None,
                       blocks: dict | None = None, num_rows: int | None = None,
-                      row_base=0, interpret: bool = True) -> jnp.ndarray:
+                      row_base=0) -> jnp.ndarray:
         """Eq. 1's neighbour mean — the model's single aggregation entry.
 
         ``blocks`` (from ``kernels.ops.build_vjp_blocks``) selects the
@@ -137,7 +137,7 @@ class GraphSAGE:
         if blocks is not None:
             from ..kernels.ops import segment_mean_op
             return segment_mean_op(x, blocks, num_rows=num_rows,
-                                   row_base=row_base, interpret=interpret)
+                                   row_base=row_base)
         return x.mean(axis=axis)
 
     # ------------------------------------------------------- sampled apply
@@ -182,7 +182,6 @@ class GraphSAGE:
         *,
         blocks: dict | None = None,   # prebuilt ops.build_vjp_blocks arrays
         use_pallas: bool = True,
-        interpret: bool = True,
     ) -> jnp.ndarray:
         """Full-graph n-layer forward -> (N, num_classes) logits.
 
@@ -191,14 +190,16 @@ class GraphSAGE:
         through the canonical jnp reference ``kernels.ref.segment_agg_ref``
         — the same two backends every other forward consumes.  ``blocks``
         may be passed prebuilt; otherwise it is built host-side from the
-        edge lists, which requires them CONCRETE — under ``jit`` with traced
-        edges the call transparently falls back to the (equally
-        differentiable) jnp reference, preserving the pre-blocks jit
-        contract.
+        edge lists, which requires them CONCRETE: traced edges without
+        ``blocks`` raise instead of silently switching backends (build the
+        blocks outside ``jit``, or pass ``use_pallas=False``).
         """
         if use_pallas and blocks is None and any(
                 isinstance(e, jax.core.Tracer) for e in (edge_src, edge_dst)):
-            use_pallas = False
+            raise ValueError(
+                "apply_full's Pallas path builds its block structure on the "
+                "host and needs concrete edge lists; under jit pass prebuilt "
+                "blocks (kernels.ops.build_vjp_blocks) or use_pallas=False")
         if use_pallas:
             if blocks is None:
                 from ..kernels.ops import build_vjp_blocks
@@ -207,7 +208,7 @@ class GraphSAGE:
                                           num_rows=num_nodes,
                                           num_src_rows=num_nodes)
             mean_agg = lambda h: self.neighbor_mean(
-                h, blocks=blocks, num_rows=num_nodes, interpret=interpret)
+                h, blocks=blocks, num_rows=num_nodes)
         else:
             from ..kernels.ref import segment_agg_ref
             mean_agg = lambda h: segment_agg_ref(

@@ -1,5 +1,5 @@
-# Pallas TPU kernels for the compute hot-spots (validated with interpret=True
-# on CPU; target is TPU v5e).  ops.py = jit wrappers, ref.py = jnp oracles.
+# Pallas TPU kernels for the compute hot-spots (compiled on a TPU v5e,
+# interpreted elsewhere).  ops.py = jit wrappers, ref.py = jnp oracles.
 from . import ops, ref
 
 __all__ = ["ops", "ref"]

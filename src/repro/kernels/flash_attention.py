@@ -31,11 +31,6 @@ DEFAULT_BQ = 128
 DEFAULT_BK = 256
 NEG_INF = -1e30
 
-# jax renamed TPUCompilerParams -> CompilerParams in newer releases; resolve
-# whichever this jax ships so the kernel builds across the 0.4.x/0.5.x line.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
@@ -146,7 +141,7 @@ def flash_attention_pallas(
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

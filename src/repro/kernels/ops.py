@@ -1,8 +1,9 @@
 """jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True because this container is CPU-only; the launch
-configs flip it to False on real TPU hardware.  Every wrapper has the same
-signature as its `ref.py` oracle so call sites (and tests) can swap them 1:1.
+The segment-aggregation wrappers run the Pallas kernel compiled on a TPU and
+interpreted elsewhere (``segment_agg.default_interpret``).  Every wrapper
+has the same signature as its `ref.py` oracle so call sites (and tests) can
+swap them 1:1.
 """
 from __future__ import annotations
 
@@ -16,7 +17,7 @@ from . import ref
 from .flash_attention import flash_attention_pallas
 from .rmsnorm import rmsnorm_pallas
 from .segment_agg import (EdgeBlocks, build_edge_blocks, build_vjp_blocks,
-                          segment_agg_pallas, segment_mean_op)
+                          segment_mean_op)
 
 __all__ = [
     "segment_agg", "make_segment_agg", "segment_mean_op", "build_vjp_blocks",
@@ -36,7 +37,7 @@ def make_mean_blocks(indptr: np.ndarray, indices: np.ndarray) -> dict:
 
 
 def make_segment_agg(indptr: np.ndarray, indices: np.ndarray, *, mean: bool = True,
-                     interpret: bool = True, use_pallas: bool = True):
+                     use_pallas: bool = True):
     """Bind the static CSR block structure once per graph; returns
     ``agg(x) -> (N, D)`` suitable for jit closure.
 
@@ -54,16 +55,15 @@ def make_segment_agg(indptr: np.ndarray, indices: np.ndarray, *, mean: bool = Tr
               for k, v in make_mean_blocks(indptr, indices).items()}
 
     def agg(x: jnp.ndarray) -> jnp.ndarray:
-        return segment_mean_op(x, blocks, num_rows=n, mean=mean,
-                               interpret=interpret)
+        return segment_mean_op(x, blocks, num_rows=n, mean=mean)
 
     return agg
 
 
-def segment_agg(x, indptr, indices, *, mean: bool = True, interpret: bool = True):
+def segment_agg(x, indptr, indices, *, mean: bool = True):
     """One-shot convenience (rebuilds block structure; prefer make_segment_agg)."""
-    return make_segment_agg(np.asarray(indptr), np.asarray(indices), mean=mean,
-                            interpret=interpret)(x)
+    return make_segment_agg(np.asarray(indptr), np.asarray(indices),
+                            mean=mean)(x)
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "q_offset", "interpret",

@@ -3,19 +3,26 @@
 GraphSAGE's Eq. 1 mean-aggregation is an SpMM: out[v] = Σ_{u∈N(v)} x[u] / |N(v)|.
 A CUDA implementation scatters with atomics; TPUs have no scatter-atomics, so
 we ADAPT (DESIGN.md §2): destination nodes are grouped into blocks of ``BN``
-consecutive rows whose incoming edges (contiguous in CSR!) are padded to a
-common ``BE``; the gather ``msgs = x[src]`` stays in XLA (which lowers it to
-efficient dynamic-slices), and the kernel performs the reduction as a
-**one-hot × message matmul on the MXU**:
+consecutive rows, whose incoming edges (contiguous in CSR!) are cut into
+chunks of ``BEC`` edges; the gather ``msgs = x[src]`` stays in XLA (which
+lowers it to efficient dynamic-slices), and the kernel performs the
+reduction as a **one-hot × message matmul on the MXU**:
 
-    acc(BN, BD) += onehot(local_dst)(BN, BEC) @ msgs(BEC, BD)
+    out(BN, D) += onehot(local_dst)(BN, BEC) @ msgs(BEC, D)
 
 i.e. the irregular segment-sum becomes a dense systolic matmul — the
-TPU-native rendering of scatter-add.  Feature dim is tiled to ``BD`` lanes
-(multiples of 128); edge chunks ``BEC`` feed the MXU contraction dim.
+TPU-native rendering of scatter-add.
 
-VMEM per grid cell ≈ BE·BD·4 (msgs) + BN·BD·4 (acc) + O(BE) indices
-≈ 1024·256·4 + 128·256·4 ≈ 1.2 MiB « 16 MiB VMEM.
+Layout is RAGGED: a node block owns ``max(1, ceil(edges / BEC))`` chunks
+(only its last chunk is padded), so padded edges are at most one chunk per
+block instead of the power-law hub's in-degree times every block.  The grid
+walks the chunks in order (an ``"arbitrary"`` reduction axis); a
+scalar-prefetched chunk→block map points the output BlockSpec at the
+chunk's node block, so the ``(BN, D)`` accumulator stays resident in VMEM
+across the block's consecutive chunks and is written back once.  VMEM per
+grid step is one ``(BEC, D)`` message chunk plus one ``(BN, D)``
+accumulator, whatever the in-degree.  The feature axis is never padded in
+HBM: each block spans the full ``D``.
 
 The op is DIFFERENTIABLE end-to-end: :func:`segment_mean_op` wraps the
 forward in a ``jax.custom_vjp`` whose backward is the transpose aggregation
@@ -32,71 +39,91 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["EdgeBlocks", "build_edge_blocks", "build_edge_blocks_from_edges",
-           "build_transpose_blocks", "build_vjp_blocks", "segment_agg_pallas",
+           "build_transpose_blocks", "build_vjp_blocks", "default_interpret",
            "segment_agg_blocks", "segment_agg_rows", "segment_agg_bwd_blocks",
-           "segment_mean_op", "pallas_call_count", "reset_pallas_call_count"]
+           "segment_mean_op", "pallas_call_count", "interpreted_call_count",
+           "reset_pallas_call_count"]
 
 BN = 128    # destination nodes per block
-BD = 256    # feature lanes per block (multiple of 128)
-BEC = 128   # edge chunk fed to the MXU contraction per step
+BEC = 128   # edges per chunk: the MXU contraction of one grid step
 
 # Trace-time observability: bumped every time the Pallas kernel is staged
-# into a jaxpr.  Lets callers (and tests) assert the kernel is actually on
-# the hot path rather than silently swapped for the jnp reference.
+# into a jaxpr (and, separately, every time it is staged in interpret mode).
+# Lets callers (and tests) assert the kernel is actually on the hot path
+# rather than silently swapped for the jnp reference, and that a chip run
+# staged no interpreted kernel.
 _PALLAS_CALLS = 0
+_INTERPRETED_CALLS = 0
 
 
 def pallas_call_count() -> int:
     return _PALLAS_CALLS
 
 
+def interpreted_call_count() -> int:
+    return _INTERPRETED_CALLS
+
+
 def reset_pallas_call_count() -> None:
-    global _PALLAS_CALLS
+    global _PALLAS_CALLS, _INTERPRETED_CALLS
     _PALLAS_CALLS = 0
+    _INTERPRETED_CALLS = 0
+
+
+def default_interpret() -> bool:
+    """Pallas interpret mode, derived from the backend: compiled on a TPU,
+    interpreted (the kernel body run as plain XLA ops) everywhere else."""
+    return jax.default_backend() != "tpu"
 
 
 @dataclass(frozen=True)
 class EdgeBlocks:
-    """Static, padded block structure for one CSR graph (host preprocessing)."""
+    """Static, ragged block structure for one CSR graph (host preprocessing).
+
+    Chunks are in node-block order; every block owns at least one chunk so
+    the kernel writes every output row."""
 
     num_nodes: int
-    num_blocks: int
-    edges_per_block: int       # BE (multiple of BEC)
-    src: np.ndarray            # (num_blocks, BE) int32, pad -> 0 (masked)
-    local_dst: np.ndarray      # (num_blocks, BE) int32 in [0, BN), pad -> 0
-    mask: np.ndarray           # (num_blocks, BE) float32
-    deg: np.ndarray            # (num_blocks, BN) float32 (>=1 where real)
+    num_blocks: int            # nb = max(1, ceil(num_nodes / BN))
+    num_chunks: int            # T >= nb
+    src: np.ndarray            # (T, BEC) int32, pad -> 0 (masked)
+    local_dst: np.ndarray      # (T, BEC) int32 in [0, BN), pad -> 0
+    mask: np.ndarray           # (T, BEC) float32
+    chunk_block: np.ndarray    # (T,) int32 node block of each chunk, sorted
+    deg: np.ndarray            # (nb, BN) float32 (>=1 where real)
 
 
 def build_edge_blocks(indptr: np.ndarray, indices: np.ndarray, bn: int = BN,
                       bec: int = BEC) -> EdgeBlocks:
+    indptr = np.asarray(indptr, dtype=np.int64)
     n = len(indptr) - 1
-    nblocks = (n + bn - 1) // bn
-    counts = [int(indptr[min((b + 1) * bn, n)] - indptr[b * bn]) for b in range(nblocks)]
-    be = max(bec, ((max(counts) + bec - 1) // bec) * bec) if counts else bec
+    nb = max(1, (n + bn - 1) // bn)
+    lo = indptr[np.minimum(np.arange(nb) * bn, n)]
+    hi = indptr[np.minimum((np.arange(nb) + 1) * bn, n)]
+    chunks = np.maximum(1, (hi - lo + bec - 1) // bec)
+    first = np.cumsum(chunks) - chunks            # first chunk of each block
+    t = int(chunks.sum())
 
-    src = np.zeros((nblocks, be), dtype=np.int32)
-    ldst = np.zeros((nblocks, be), dtype=np.int32)
-    mask = np.zeros((nblocks, be), dtype=np.float32)
-    deg = np.ones((nblocks, bn), dtype=np.float32)
-    for b in range(nblocks):
-        lo_node, hi_node = b * bn, min((b + 1) * bn, n)
-        lo, hi = int(indptr[lo_node]), int(indptr[hi_node])
-        k = hi - lo
-        src[b, :k] = indices[lo:hi]
-        dst_global = np.repeat(
-            np.arange(lo_node, hi_node),
-            np.diff(indptr[lo_node : hi_node + 1]),
-        )
-        ldst[b, :k] = dst_global - lo_node
-        mask[b, :k] = 1.0
-        d = np.diff(indptr[lo_node : hi_node + 1]).astype(np.float32)
-        deg[b, : hi_node - lo_node] = np.maximum(d, 1.0)
+    dst = np.repeat(np.arange(n), np.diff(indptr))
+    blk = dst // bn
+    slot = first[blk] * bec + (np.arange(len(dst)) - lo[blk])
+    src = np.zeros(t * bec, dtype=np.int32)
+    ldst = np.zeros(t * bec, dtype=np.int32)
+    mask = np.zeros(t * bec, dtype=np.float32)
+    src[slot] = np.asarray(indices)[: len(dst)]
+    ldst[slot] = dst - blk * bn
+    mask[slot] = 1.0
+    deg = np.ones(nb * bn, dtype=np.float32)
+    deg[:n] = np.maximum(np.diff(indptr), 1)
     return EdgeBlocks(
-        num_nodes=n, num_blocks=nblocks, edges_per_block=be,
-        src=src, local_dst=ldst, mask=mask, deg=deg,
+        num_nodes=n, num_blocks=nb, num_chunks=t,
+        src=src.reshape(t, bec), local_dst=ldst.reshape(t, bec),
+        mask=mask.reshape(t, bec),
+        chunk_block=np.repeat(np.arange(nb, dtype=np.int32), chunks),
+        deg=deg.reshape(nb, bn),
     )
 
 
@@ -125,18 +152,6 @@ def build_transpose_blocks(src: np.ndarray, dst: np.ndarray,
     return build_edge_blocks_from_edges(dst, src, num_src_rows, bn=bn, bec=bec)
 
 
-def _pad_min_one_block(blocks: EdgeBlocks, bn: int) -> EdgeBlocks:
-    """Guarantee >= 1 (all-pad) block so empty edge sets still stage a valid
-    kernel grid — the same guard engine.stacking applies when stacking."""
-    if blocks.num_blocks:
-        return blocks
-    be = blocks.edges_per_block
-    return EdgeBlocks(
-        num_nodes=blocks.num_nodes, num_blocks=1, edges_per_block=be,
-        src=np.zeros((1, be), np.int32), local_dst=np.zeros((1, be), np.int32),
-        mask=np.zeros((1, be), np.float32), deg=np.ones((1, bn), np.float32))
-
-
 def build_vjp_blocks(src: np.ndarray, dst: np.ndarray, num_rows: int,
                      num_src_rows: int, bn: int = BN,
                      bec: int = BEC) -> dict[str, np.ndarray]:
@@ -147,106 +162,124 @@ def build_vjp_blocks(src: np.ndarray, dst: np.ndarray, num_rows: int,
 
     ``num_rows`` is the aggregation's output row range (destinations live in
     ``[0, num_rows)``); ``num_src_rows`` is the gathered-from row space the
-    gradient must cover (sources live in ``[0, num_src_rows)``).
+    gradient must cover (sources live in ``[0, num_src_rows)``; the op's
+    input ``x`` has exactly this many rows).
     """
-    fwd = _pad_min_one_block(
-        build_edge_blocks_from_edges(src, dst, num_rows, bn=bn, bec=bec), bn)
-    bwd = _pad_min_one_block(
-        build_transpose_blocks(src, dst, num_src_rows, bn=bn, bec=bec), bn)
+    fwd = build_edge_blocks_from_edges(src, dst, num_rows, bn=bn, bec=bec)
+    bwd = build_transpose_blocks(src, dst, num_src_rows, bn=bn, bec=bec)
     return {"src": fwd.src, "dst": fwd.local_dst, "mask": fwd.mask,
-            "deg": fwd.deg, "t_src": bwd.src, "t_dst": bwd.local_dst,
-            "t_mask": bwd.mask}
+            "blk": fwd.chunk_block, "deg": fwd.deg,
+            "t_src": bwd.src, "t_dst": bwd.local_dst, "t_mask": bwd.mask,
+            "t_blk": bwd.chunk_block}
 
 
-def _segment_agg_kernel(msgs_ref, ldst_ref, mask_ref, deg_ref, out_ref, *, be: int,
-                        bn: int, mean: bool):
-    """One (node-block, feature-block) grid cell."""
+def _segment_agg_kernel(blk_ref, ldst_ref, mask_ref, msgs_ref, deg_ref,
+                        out_ref, *, mean: bool):
+    """One edge chunk of one node block; ``out_ref`` is the block's
+    accumulator, resident across the block's consecutive chunks."""
+    t = pl.program_id(0)
+    last = pl.num_programs(0) - 1
+    b = blk_ref[t]
     # accumulate in the input precision for float64 (interpret-mode oracles
     # and the fp64 grad checks need exact arithmetic), float32 otherwise
-    acc_dt = jnp.float64 if msgs_ref.dtype == jnp.float64 else jnp.float32
-    acc = jnp.zeros((bn, msgs_ref.shape[-1]), dtype=acc_dt)
-    ldst = ldst_ref[0]          # (BE,)
-    mask = mask_ref[0]          # (BE,)
-    rows = jax.lax.broadcasted_iota(jnp.int32, (bn, BEC), 0)
+    acc_dt = out_ref.dtype
 
-    def chunk(e, acc):
-        sl = pl.dslice(e * BEC, BEC)
-        m = msgs_ref[sl, :].astype(acc_dt)                   # (BEC, BD)
-        d = jax.lax.dynamic_slice(ldst, (e * BEC,), (BEC,))  # (BEC,)
-        w = jax.lax.dynamic_slice(mask, (e * BEC,), (BEC,)).astype(acc_dt)
-        onehot = jnp.where(rows == d[None, :], w[None, :],
-                           jnp.zeros((), acc_dt))            # (BN, BEC)
-        return acc + jax.lax.dot_general(
-            onehot, m, (((1,), (0,)), ((), ())),
-            preferred_element_type=acc_dt,
-        )
+    @pl.when((t == 0) | (blk_ref[jnp.maximum(t - 1, 0)] != b))
+    def _init():
+        out_ref[...] = jnp.zeros(out_ref.shape, acc_dt)
 
-    acc = jax.lax.fori_loop(0, be // BEC, chunk, acc)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (out_ref.shape[0],
+                                                ldst_ref.shape[-1]), 0)
+    onehot = jnp.where(rows == ldst_ref[...], mask_ref[...].astype(acc_dt),
+                       jnp.zeros((), acc_dt))                 # (BN, BEC)
+    out_ref[...] += jax.lax.dot_general(
+        onehot, msgs_ref[...].astype(acc_dt), (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=acc_dt)
+
     if mean:
-        acc = acc / deg_ref[0][:, None].astype(acc_dt)
-    out_ref[...] = acc.astype(out_ref.dtype)
+        @pl.when((t == last) | (blk_ref[jnp.minimum(t + 1, last)] != b))
+        def _finish():
+            out_ref[...] = out_ref[...] / deg_ref[...].astype(acc_dt)
 
 
 def segment_agg_blocks(
-    msgs: jnp.ndarray,        # (num_blocks * BE, D) gathered edge messages
-    local_dst: jnp.ndarray,   # (num_blocks, BE) int32 in [0, BN)
-    mask: jnp.ndarray,        # (num_blocks, BE) float32
-    deg: jnp.ndarray,         # (num_blocks, BN) float32 (>=1 where real)
+    msgs: jnp.ndarray,        # (T * BEC, D) gathered edge messages
+    local_dst: jnp.ndarray,   # (T, BEC) int32 in [0, BN)
+    mask: jnp.ndarray,        # (T, BEC) float32
+    chunk_block: jnp.ndarray,  # (T,) int32 node block of each chunk
+    deg: jnp.ndarray,         # (nb, BN) float32 (>=1 where real)
     *,
     mean: bool = True,
-    bd: int = BD,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Array-based kernel entry: the block structure arrives as (possibly
     traced) arrays, so the call nests cleanly under ``vmap`` / ``shard_map``
     where each program instance owns a different partition's blocks.  Only
     the SHAPES must agree across instances (the SPMD engine pads them to a
-    common (nb, BE)).  Returns (num_blocks * BN, D); caller unpads rows.
+    common (T, nb)).  Returns (nb * BN, D); caller unpads rows.
 
-    ``interpret=True`` runs the kernel body in Python on CPU (this container);
-    on a real TPU pass ``interpret=False``.
+    ``interpret`` defaults to :func:`default_interpret`; passing ``False``
+    asks for the TPU lowering (e.g. to compile for a described chip).
     """
-    global _PALLAS_CALLS
+    global _PALLAS_CALLS, _INTERPRETED_CALLS
+    if interpret is None:
+        interpret = default_interpret()
     _PALLAS_CALLS += 1
-    nb, be = local_dst.shape
-    bn = deg.shape[-1]
+    _INTERPRETED_CALLS += bool(interpret)
+    t, bec = local_dst.shape
+    nb, bn = deg.shape
     d = msgs.shape[-1]
-    d_pad = ((d + bd - 1) // bd) * bd
-    if d_pad != d:
-        msgs = jnp.pad(msgs, ((0, 0), (0, d_pad - d)))
+    acc_dt = jnp.float64 if msgs.dtype == jnp.float64 else jnp.float32
 
     out = pl.pallas_call(
-        functools.partial(_segment_agg_kernel, be=be, bn=bn, mean=mean),
-        grid=(nb, d_pad // bd),
-        in_specs=[
-            pl.BlockSpec((be, bd), lambda b, f: (b, f)),       # msgs
-            pl.BlockSpec((1, be), lambda b, f: (b, 0)),        # local dst
-            pl.BlockSpec((1, be), lambda b, f: (b, 0)),        # mask
-            pl.BlockSpec((1, bn), lambda b, f: (b, 0)),        # deg
-        ],
-        out_specs=pl.BlockSpec((bn, bd), lambda b, f: (b, f)),
-        out_shape=jax.ShapeDtypeStruct((nb * bn, d_pad), msgs.dtype),
+        functools.partial(_segment_agg_kernel, mean=mean),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(t,),
+            in_specs=[
+                pl.BlockSpec((None, 1, bec), lambda i, blk: (i, 0, 0)),
+                pl.BlockSpec((None, 1, bec), lambda i, blk: (i, 0, 0)),
+                pl.BlockSpec((bec, d), lambda i, blk: (i, 0)),
+                pl.BlockSpec((bn, 1), lambda i, blk: (blk[i], 0)),
+            ],
+            out_specs=pl.BlockSpec((bn, d), lambda i, blk: (blk[i], 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((nb * bn, d), acc_dt),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="segment_agg",
     )(
-        msgs.reshape(nb * be, d_pad),
-        jnp.asarray(local_dst),
-        jnp.asarray(mask),
-        jnp.asarray(deg),
+        jnp.asarray(chunk_block, jnp.int32),
+        jnp.asarray(local_dst, jnp.int32).reshape(t, 1, bec),
+        jnp.asarray(mask).reshape(t, 1, bec),
+        msgs.reshape(t * bec, d),
+        jnp.asarray(deg).reshape(nb * bn, 1),
     )
-    return out[:, :d]
+    return out.astype(msgs.dtype)
+
+
+def _place(out: jnp.ndarray, row_base, num_rows: int) -> jnp.ndarray:
+    """Place kernel output rows at the (possibly traced) ``row_base`` inside
+    a zero ``(num_rows, D)`` output; the target is padded by the block rows
+    so dynamic_update_slice never clamps for row_base <= num_rows."""
+    target = jnp.zeros((num_rows + out.shape[0], out.shape[1]), out.dtype)
+    target = jax.lax.dynamic_update_slice(
+        target, out, (jnp.asarray(row_base, jnp.int32), jnp.int32(0)))
+    return target[:num_rows]
 
 
 def segment_agg_rows(
-    msgs: jnp.ndarray,        # (num_blocks * BE, D) gathered edge messages
-    local_dst: jnp.ndarray,   # (num_blocks, BE) int32 in [0, BN)
-    mask: jnp.ndarray,        # (num_blocks, BE) float32
-    deg: jnp.ndarray,         # (num_blocks, BN) float32 (>=1 where real)
+    msgs: jnp.ndarray,        # (T * BEC, D) gathered edge messages
+    local_dst: jnp.ndarray,   # (T, BEC) int32 in [0, BN)
+    mask: jnp.ndarray,        # (T, BEC) float32
+    chunk_block: jnp.ndarray,  # (T,) int32
+    deg: jnp.ndarray,         # (nb, BN) float32 (>=1 where real)
     *,
     row_base,                 # int or traced scalar: first output row
     num_rows: int,            # static total output rows
     mean: bool = True,
-    bd: int = BD,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Row-range (masked) kernel entry: aggregate a REBASED sub-range of the
     node space and place it at ``row_base`` inside a zero ``(num_rows, D)``
@@ -261,29 +294,9 @@ def segment_agg_rows(
     empty range (all-pad blocks, the zero-boundary partition) yields an
     all-zero output.
     """
-    out = segment_agg_blocks(msgs, local_dst, mask, deg, mean=mean, bd=bd,
-                             interpret=interpret)
-    # place at the (possibly traced) row offset; the target is padded by the
-    # block rows so dynamic_update_slice never clamps for row_base <= num_rows
-    target = jnp.zeros((num_rows + out.shape[0], out.shape[1]), out.dtype)
-    target = jax.lax.dynamic_update_slice(
-        target, out, (jnp.asarray(row_base, jnp.int32), jnp.int32(0)))
-    return target[:num_rows]
-
-
-def segment_agg_pallas(
-    msgs: jnp.ndarray,        # (num_blocks * BE, D) gathered edge messages
-    blocks: EdgeBlocks,
-    *,
-    mean: bool = True,
-    bd: int = BD,
-    interpret: bool = True,
-) -> jnp.ndarray:
-    """Blocked segment sum/mean over a host-built :class:`EdgeBlocks`."""
-    return segment_agg_blocks(
-        msgs, jnp.asarray(blocks.local_dst), jnp.asarray(blocks.mask),
-        jnp.asarray(blocks.deg), mean=mean, bd=bd, interpret=interpret,
-    )
+    out = segment_agg_blocks(msgs, local_dst, mask, chunk_block, deg,
+                             mean=mean, interpret=interpret)
+    return _place(out, row_base, num_rows)
 
 
 # ---------------------------------------------------------------------------
@@ -308,25 +321,20 @@ class _MeanOpMeta:
     n_in: int        # rows of x the gradient must cover
     mean: bool
     interpret: bool
-    bd: int
 
 
-def _segment_mean_fwd_impl(meta: _MeanOpMeta, x, src, dst, mask, deg, row_base):
-    msgs = x[src.reshape(-1)]                   # XLA gather, per-block layout
-    out = segment_agg_blocks(msgs, dst, mask, deg, mean=meta.mean, bd=meta.bd,
+def _segment_mean_fwd_impl(meta: _MeanOpMeta, x, src, dst, mask, blk, deg,
+                           row_base):
+    msgs = x[src.reshape(-1)]                   # XLA gather, per-chunk layout
+    out = segment_agg_blocks(msgs, dst, mask, blk, deg, mean=meta.mean,
                              interpret=meta.interpret)
-    # place at the (possibly traced) row offset; the target is padded by the
-    # block rows so dynamic_update_slice never clamps for row_base <= num_rows
-    target = jnp.zeros((meta.num_rows + out.shape[0], out.shape[1]), out.dtype)
-    target = jax.lax.dynamic_update_slice(
-        target, out, (jnp.asarray(row_base, jnp.int32), jnp.int32(0)))
-    return target[:meta.num_rows]
+    return _place(out, row_base, meta.num_rows)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _segment_mean_core(meta, x, src, dst, mask, deg, t_src, t_dst, t_mask,
-                       row_base):
-    return _segment_mean_fwd_impl(meta, x, src, dst, mask, deg, row_base)
+def _segment_mean_core(meta, x, src, dst, mask, blk, deg, t_src, t_dst,
+                       t_mask, t_blk, row_base):
+    return _segment_mean_fwd_impl(meta, x, src, dst, mask, blk, deg, row_base)
 
 
 def segment_agg_bwd_blocks(
@@ -336,8 +344,7 @@ def segment_agg_bwd_blocks(
     n_in: int,                # rows of the x space to produce
     mean: bool = True,
     row_base=0,               # int or traced scalar (matches the forward)
-    bd: int = BD,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Source-blocked BACKWARD kernel entry: scale the output cotangent by
     the forward 1/deg (mean) and aggregate it dst -> src through the same
@@ -350,6 +357,8 @@ def segment_agg_bwd_blocks(
     recurses through the same custom VJP instead of hitting the raw
     ``pallas_call``.
     """
+    if interpret is None:
+        interpret = default_interpret()
     deg = blocks["deg"]
     d_feat = g.shape[-1]
     range_cap = deg.shape[0] * deg.shape[1]     # rows the fwd kernel produced
@@ -364,33 +373,37 @@ def segment_agg_bwd_blocks(
     if mean:
         gsub = gsub / deg.reshape(-1)[:, None].astype(gsub.dtype)
     meta_t = _MeanOpMeta(num_rows=n_in, n_in=range_cap, mean=False,
-                         interpret=interpret, bd=bd)
-    t_deg = jnp.ones((blocks["t_dst"].shape[0], deg.shape[-1]), jnp.float32)
+                         interpret=interpret)
+    # the transpose blocks cover the n_in source rows (build_vjp_blocks'
+    # num_src_rows); their degree is unused by the sum
+    t_deg = jnp.ones((max(1, -(-n_in // deg.shape[1])), deg.shape[1]),
+                     jnp.float32)
     return _segment_mean_core(
         meta_t, gsub, blocks["t_src"], blocks["t_dst"], blocks["t_mask"],
-        t_deg, blocks["src"], blocks["dst"], blocks["mask"],
-        jnp.int32(0))
+        blocks["t_blk"], t_deg, blocks["src"], blocks["dst"], blocks["mask"],
+        blocks["blk"], jnp.int32(0))
 
 
-def _segment_mean_fwd(meta, x, src, dst, mask, deg, t_src, t_dst, t_mask,
-                      row_base):
+def _segment_mean_fwd(meta, x, src, dst, mask, blk, deg, t_src, t_dst, t_mask,
+                      t_blk, row_base):
     # re-enter the custom-vjp op (not the raw impl): higher-order AD
     # differentiates the fwd/bwd RULES, so both must resolve to the custom
     # VJP again instead of exposing the raw pallas_call to jvp/transpose
-    out = _segment_mean_core(meta, x, src, dst, mask, deg, t_src, t_dst,
-                             t_mask, row_base)
-    return out, (src, dst, mask, deg, t_src, t_dst, t_mask, row_base)
+    out = _segment_mean_core(meta, x, src, dst, mask, blk, deg, t_src, t_dst,
+                             t_mask, t_blk, row_base)
+    return out, (src, dst, mask, blk, deg, t_src, t_dst, t_mask, t_blk,
+                 row_base)
 
 
 def _segment_mean_bwd(meta, res, g):
-    src, dst, mask, deg, t_src, t_dst, t_mask, row_base = res
-    blocks = {"src": src, "dst": dst, "mask": mask, "deg": deg,
-              "t_src": t_src, "t_dst": t_dst, "t_mask": t_mask}
+    src, dst, mask, blk, deg, t_src, t_dst, t_mask, t_blk, row_base = res
+    blocks = {"src": src, "dst": dst, "mask": mask, "blk": blk, "deg": deg,
+              "t_src": t_src, "t_dst": t_dst, "t_mask": t_mask,
+              "t_blk": t_blk}
     gx = segment_agg_bwd_blocks(g, blocks, n_in=meta.n_in, mean=meta.mean,
-                                row_base=row_base, bd=meta.bd,
-                                interpret=meta.interpret)
+                                row_base=row_base, interpret=meta.interpret)
     # block structure and row offset are static graph data: zero cotangents
-    return (gx, None, None, None, None, None, None, None, None)
+    return (gx,) + (None,) * 10
 
 
 _segment_mean_core.defvjp(_segment_mean_fwd, _segment_mean_bwd)
@@ -403,8 +416,7 @@ def segment_mean_op(
     num_rows: int,                  # static output rows
     row_base=0,                     # int or traced scalar: first output row
     mean: bool = True,
-    interpret: bool = True,
-    bd: int = BD,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """THE differentiable blocked aggregation op (every forward's Eq. 1).
 
@@ -420,9 +432,11 @@ def segment_mean_op(
     stacked) arrays from :func:`build_vjp_blocks`; only shapes must be
     static.
     """
+    if interpret is None:
+        interpret = default_interpret()
     meta = _MeanOpMeta(num_rows=int(num_rows), n_in=int(x.shape[0]),
-                       mean=bool(mean), interpret=bool(interpret), bd=int(bd))
+                       mean=bool(mean), interpret=bool(interpret))
     return _segment_mean_core(
-        meta, x, blocks["src"], blocks["dst"], blocks["mask"], blocks["deg"],
-        blocks["t_src"], blocks["t_dst"], blocks["t_mask"],
-        jnp.asarray(row_base, jnp.int32))
+        meta, x, blocks["src"], blocks["dst"], blocks["mask"], blocks["blk"],
+        blocks["deg"], blocks["t_src"], blocks["t_dst"], blocks["t_mask"],
+        blocks["t_blk"], jnp.asarray(row_base, jnp.int32))

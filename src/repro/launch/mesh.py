@@ -2,18 +2,15 @@
 
 A FUNCTION (not a module-level constant) so importing this module never
 touches jax device state — the dry-run sets XLA_FLAGS before first init.
-
-``make_mesh_compat`` papers over the ``jax.make_mesh`` signature drift:
-newer jax takes ``axis_types=(AxisType.Auto, ...)``, jax 0.4.x predates
-``jax.sharding.AxisType`` entirely.  All mesh construction in this repo
-(production, tests, the SPMD engine) goes through it.
+All mesh construction in this repo (production, tests, the SPMD engine)
+builds ``jax.make_mesh`` meshes with explicit ``Auto`` axis types.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = [
-    "make_mesh_compat",
     "make_production_mesh",
     "make_partition_mesh",
     "data_axes_of",
@@ -21,42 +18,27 @@ __all__ = [
 ]
 
 
-def make_mesh_compat(shape: tuple[int, ...], axes: tuple[str, ...],
-                     devices=None):
-    """Version-portable ``jax.make_mesh`` (auto axis types where supported)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    kwargs = {} if devices is None else {"devices": devices}
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(axis_type.Auto,) * len(axes),
-                                 **kwargs)
-        except TypeError:  # make_mesh without axis_types kwarg
-            pass
-    return jax.make_mesh(shape, axes, **kwargs)
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """Single pod: (16, 16) = 256 chips, axes (data, model).
     Multi-pod:  (2, 16, 16) = 512 chips, axes (pod, data, model)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_partition_mesh(num_parts: int, axis_name: str = "parts"):
     """1-D mesh over ``num_parts`` devices for the SPMD engine's shard_map
-    path.  Requires at least ``num_parts`` visible devices (e.g. via
-    ``--xla_force_host_platform_device_count``); callers should fall back to
-    the stacked vmap path otherwise."""
+    path: one graph partition per device.  Requires at least ``num_parts``
+    visible devices; callers should use the stacked vmap path otherwise."""
     devices = jax.devices()
     if len(devices) < num_parts:
         raise ValueError(
             f"need {num_parts} devices for the partition mesh, "
             f"have {len(devices)}"
         )
-    return make_mesh_compat((num_parts,), (axis_name,),
-                            devices=devices[:num_parts])
+    return jax.make_mesh((num_parts,), (axis_name,),
+                         axis_types=(AxisType.Auto,),
+                         devices=devices[:num_parts])
 
 
 def data_axes_of(mesh) -> tuple[str, ...]:

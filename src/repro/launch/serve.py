@@ -124,6 +124,8 @@ def main() -> int:
                     help="rolling sliding-window cache serving variant")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.gnn:
         return gnn_main(args)

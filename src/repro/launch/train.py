@@ -15,8 +15,10 @@ CBS sampling → GP two-phase training):
 
 The gnn mode executes through the SPMD engine (repro.engine): with >= N
 devices each epoch runs as one ``shard_map`` step over a partition mesh;
-on a single CPU the SAME per-shard program runs under ``vmap`` with
-identical collective semantics (DESIGN.md §3).  ``--engine sequential``
+on a single device the SAME per-shard program runs under ``vmap`` with
+identical collective semantics (DESIGN.md §3).  On a CPU host, several
+devices exist only when the environment asks for them before Python
+starts (``XLA_FLAGS=--xla_force_host_platform_device_count=N``).  ``--engine sequential``
 selects the legible per-partition Python-loop reference, which the engine
 reproduces bit-for-bit in float64 (tests/test_engine_parity.py).
 """
@@ -31,16 +33,6 @@ import numpy as np
 
 
 def run_gnn(args) -> dict:
-    if args.engine == "spmd":
-        # a partition mesh needs >= parts devices; on a plain CPU host force
-        # XLA's host-platform device split BEFORE jax initialises (no-op when
-        # the flag is already set, e.g. on a real mesh)
-        import os
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                f"{flags} --xla_force_host_platform_device_count="
-                f"{args.parts}").strip()
     from repro.pipeline import EATConfig, run_eat_distgnn
 
     cfg = EATConfig(
@@ -59,7 +51,6 @@ def run_gnn(args) -> dict:
         use_pallas_agg=not args.no_pallas_agg,
         overlap_halo=args.overlap_halo,
         ring_chunks=args.ring_chunks,
-        interpret=not args.no_interpret,
         async_personalize=args.async_personalize,
         async_generalize=args.async_generalize,
         double_buffer=not args.no_double_buffer,
@@ -235,9 +226,6 @@ def main() -> int:
                         "ships per sync")
     g.add_argument("--grad-bucket-kb", type=int, default=512,
                    help="slice size of the bucketed gradient all-reduce")
-    g.add_argument("--no-interpret", action="store_true",
-                   help="run Pallas kernels compiled (real TPU) instead of "
-                        "interpret mode; pair with --engine spmd on a mesh")
     g.add_argument("--centralized", action="store_true",
                    help="single host, no partitioning (the Table IV "
                         "baseline configuration)")
@@ -324,6 +312,8 @@ def main() -> int:
     l.add_argument("--seed", type=int, default=0)
 
     args = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     if args.mode == "gnn":
         run_gnn(args)
     else:

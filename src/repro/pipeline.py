@@ -127,7 +127,6 @@ class EATConfig:
     grad_compress: str = "none"           # none | bucketed | topk
     grad_topk_frac: float = 0.01          # fraction of entries top-k ships
     grad_bucket_kb: int = 512             # bucketed psum slice size
-    interpret: bool = True                # Pallas interpret mode (False on TPU)
     # phase-0 trains FULL-GRAPH instead of sampled minibatches: one (or
     # ``full_graph_iters``) full-batch value_and_grad step(s) per epoch
     # straight through the distributed forward — halo exchange and the
@@ -239,6 +238,11 @@ class EATResult:
     resumed_from_epoch: int = -1
     # total injected straggler delay (max over hosts per epoch, summed)
     straggler_delay_s: float = 0.0
+    # per-epoch device seconds of the epoch's compiled train call (both
+    # phases, block_until_ready-timed, compilation excluded)
+    epoch_device_s: list[float] = field(default_factory=list)
+    # engine trace + lower + compile seconds over the whole run
+    compile_s: float = 0.0
 
     def summary(self) -> dict:
         return {
@@ -416,7 +420,6 @@ def run_eat_distgnn(cfg: EATConfig, verbose: bool = False,
         hp=GPHyperParams(lambda_prox=cfg.lambda_prox),
         config=EngineConfig(mode=cfg.engine_mode,
                             use_pallas_agg=cfg.use_pallas_agg,
-                            interpret=cfg.interpret,
                             dtype=fdt,
                             overlap_halo=cfg.overlap_halo,
                             ring_chunks=cfg.ring_chunks,
@@ -434,7 +437,10 @@ def run_eat_distgnn(cfg: EATConfig, verbose: bool = False,
                             feat_groups=cfg.feat_groups,
                             feat_budget_mb=cfg.feat_budget_mb))
     if verbose:
-        print(f"engine[{engine.mode}] {pg.summary()}")
+        dev = jax.devices()
+        print(f"engine[{engine.mode}] platform={dev[0].platform} "
+              f"device_kind={dev[0].device_kind} devices={len(dev)} "
+              f"{pg.summary()}")
 
     # ---------------- per-host samplers -----------------------------------
     neigh = NeighborSampler(graph, fanouts=cfg.fanouts, seed=cfg.seed)
@@ -509,6 +515,7 @@ def run_eat_distgnn(cfg: EATConfig, verbose: bool = False,
     sim_time = 0.0
     epoch_times: list[float] = []
     epoch_times_with_eval: list[float] = []
+    epoch_dev: list[float] = []
     comm_grad = 0
     comm_halo_p0 = 0
     comm_halo_p1 = 0
@@ -679,6 +686,7 @@ def run_eat_distgnn(cfg: EATConfig, verbose: bool = False,
             epoch_times = [float(x) for x in host["epoch_times"]]
             epoch_times_with_eval = [float(x)
                                      for x in host["epoch_times_with_eval"]]
+            epoch_dev = [float(x) for x in host.get("epoch_device_s", ())]
             comm_grad, comm_halo_p0, comm_halo_p1 = (
                 int(x) for x in host["comm"])
             halo_exchange_hist = [int(x) for x in host["halo_exchange_hist"]]
@@ -711,6 +719,7 @@ def run_eat_distgnn(cfg: EATConfig, verbose: bool = False,
             "sim_time": sim_time,
             "epoch_times": epoch_times,
             "epoch_times_with_eval": epoch_times_with_eval,
+            "epoch_device_s": epoch_dev,
             "comm": [int(comm_grad), int(comm_halo_p0), int(comm_halo_p1)],
             "halo_exchange_hist": [int(x) for x in halo_exchange_hist],
             "p0_iter_hist": [int(x) for x in p0_iter_hist],
@@ -801,6 +810,7 @@ def run_eat_distgnn(cfg: EATConfig, verbose: bool = False,
             halo_exchange_hist.append(ex)
             comm_halo_p0 += ex + fetch_bytes_per_epoch
         host_to_device_p0 += cold_delta()
+        epoch_dev.append(float(t_dev))
         comm_grad += grad_bytes_per_sync * iters
         p0_iter_hist.append(int(iters))
         host_time = epoch_host_times(t_host, t_dev)
@@ -825,7 +835,7 @@ def run_eat_distgnn(cfg: EATConfig, verbose: bool = False,
             best_global = params
         if verbose:
             print(f"[phase-0] epoch {ctrl.epoch:3d} loss {mean_loss:.4f} "
-                  f"val-micro {mean_val*100:.2f}")
+                  f"val-micro {mean_val*100:.2f} device {t_dev:.4f}s")
         if cfg.use_gp and ctrl.should_personalize():
             ctrl.start_personalization()
         epoch_boundary()
@@ -904,6 +914,7 @@ def run_eat_distgnn(cfg: EATConfig, verbose: bool = False,
                     jnp.asarray(budgets))
                 host_elapsed += np.where(
                     active_np, epoch_host_times(t_host, t_dev), 0.0)
+            epoch_dev.append(float(t_dev))
             ex = eval_exchange_bytes()
             halo_exchange_hist.append(ex)
             comm_halo_p1 += ex + fetch_bytes_per_epoch
@@ -919,7 +930,8 @@ def run_eat_distgnn(cfg: EATConfig, verbose: bool = False,
                 print(f"[phase-1] epoch {ctrl.epoch:3d} "
                       f"val-micro {scores.mean()*100:.2f} "
                       f"active {int(active_np.sum())}/{n_parts} "
-                      f"budgets {np.asarray(budgets).tolist()}")
+                      f"budgets {np.asarray(budgets).tolist()} "
+                      f"device {t_dev:.4f}s")
             phase1_state.update(
                 global_params=global_params, pparams=pparams, popt=popt,
                 best_personal=best_personal, host_elapsed=host_elapsed,
@@ -983,4 +995,6 @@ def run_eat_distgnn(cfg: EATConfig, verbose: bool = False,
         final_params=final_stacked,
         resumed_from_epoch=resumed_from,
         straggler_delay_s=straggler_total,
+        epoch_device_s=epoch_dev,
+        compile_s=float(getattr(engine, "compile_seconds", 0.0)),
     )

@@ -77,15 +77,15 @@ def _dense_recompute(h_prev, w_self, w_neigh, b, rows, src, dst, deg,
     return jax.nn.relu(out) if activate else out
 
 
-@partial(jax.jit, static_argnames=("activate", "interpret"))
+@partial(jax.jit, static_argnames=("activate",))
 def _pallas_recompute(h_prev, w_self, w_neigh, b, rows, blocks,
-                      activate: bool, interpret: bool):
+                      activate: bool):
     """The same recompute with the aggregation through ``segment_mean_op``
     (the blocked Pallas kernel every training forward uses)."""
     from ..kernels.ops import segment_mean_op
 
-    agg = segment_mean_op(h_prev, blocks, num_rows=int(rows.shape[0]),
-                          interpret=interpret).astype(h_prev.dtype)
+    agg = segment_mean_op(h_prev, blocks,
+                          num_rows=int(rows.shape[0])).astype(h_prev.dtype)
     out = h_prev[rows] @ w_self + agg @ w_neigh + b
     return jax.nn.relu(out) if activate else out
 
@@ -103,7 +103,7 @@ class GNNServingEngine:
     """
 
     def __init__(self, model, params, pg: PartitionedGraph, export: dict, *,
-                 use_pallas_agg: bool = False, interpret: bool = True,
+                 use_pallas_agg: bool = False,
                  hot_cache_rows: int = 256, planner_compact_after: int = 64):
         if len(params.layers) != model.num_layers:
             raise ValueError("params depth != model.num_layers")
@@ -111,7 +111,6 @@ class GNNServingEngine:
         self.params = params
         self.L = model.num_layers
         self.use_pallas_agg = bool(use_pallas_agg)
-        self.interpret = bool(interpret)
         P = pg.num_parts
         self.num_parts = P
         self.n_own = np.asarray(pg.n_own).astype(np.int64)
@@ -313,7 +312,7 @@ class GNNServingEngine:
             out = _pallas_recompute(
                 jnp.asarray(h_prev), lp.w_self, lp.w_neigh, lp.b,
                 jnp.asarray(rp), jax.tree.map(jnp.asarray, blocks),
-                activate=activate, interpret=self.interpret)
+                activate=activate)
         else:
             e = int(src.size)
             ep = _bucket(e, lo=1)

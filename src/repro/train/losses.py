@@ -12,6 +12,45 @@ __all__ = ["cross_entropy_loss", "focal_loss", "prox_penalty", "multilabel_bce_l
 PyTree = Any
 
 
+def _ordered_sum(x: jnp.ndarray, axis: int | None = None) -> jnp.ndarray:
+    """Sum (of all elements, or along ``axis``) in a fixed pairwise order
+    built from elementwise adds, so the result is bitwise the same whether
+    or not the caller runs under ``vmap`` or ``scan`` (XLA vectorises a
+    plain reduction differently in different programs, which moves the
+    last ulp — and the fp64 parity oracles compare losses bitwise)."""
+    if axis is None:
+        x, axis = x.reshape(-1), 0
+    x = jnp.moveaxis(x, axis, 0)
+    n = 1
+    while n < x.shape[0]:
+        n *= 2
+    x = jnp.pad(x, [(0, n - x.shape[0])] + [(0, 0)] * (x.ndim - 1))
+    while x.shape[0] > 1:
+        x = x[: x.shape[0] // 2] + x[x.shape[0] // 2:]
+    return x[0]
+
+
+def _log_softmax(logits: jnp.ndarray) -> jnp.ndarray:
+    """``jax.nn.log_softmax`` over the class axis with the order-fixed sum,
+    in at least float32 and in float64 for float64 logits."""
+    z = logits.astype(jnp.promote_types(logits.dtype, jnp.float32))
+    z = z - jax.lax.stop_gradient(z.max(axis=-1, keepdims=True))
+    return z - jnp.log(_ordered_sum(jnp.exp(z), axis=-1))[..., None]
+
+
+def _masked_mean(per_example: jnp.ndarray, valid: jnp.ndarray) -> jnp.ndarray:
+    """Mean over the valid examples in the terms' precision (float64 for
+    float64 logits), summed in a fixed order, returned as float32.
+
+    XLA compiles the same loss a little differently inside different
+    programs (a vmapped scan, a jitted loop), which moves the float64 mean
+    by an ulp; rounding it to float32 removes that noise, so two programs
+    computing the same loss report it bitwise equal."""
+    w = valid.astype(per_example.dtype)
+    mean = _ordered_sum(per_example * w) / jnp.maximum(_ordered_sum(w), 1.0)
+    return mean.astype(jnp.float32)
+
+
 def cross_entropy_loss(
     logits: jnp.ndarray,
     labels: jnp.ndarray,
@@ -27,12 +66,11 @@ def cross_entropy_loss(
     if mask is not None:
         valid = valid & (mask > 0)
     safe_labels = jnp.maximum(labels, 0)
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+    logp = _log_softmax(logits)
     nll = -jnp.take_along_axis(logp, safe_labels[..., None], axis=-1)[..., 0]
     if label_smoothing > 0.0:
         nll = (1.0 - label_smoothing) * nll - label_smoothing * logp.mean(axis=-1)
-    w = valid.astype(jnp.float32)
-    return jnp.sum(nll * w) / jnp.maximum(jnp.sum(w), 1.0)
+    return _masked_mean(nll, valid)
 
 
 def focal_loss(
@@ -47,12 +85,11 @@ def focal_loss(
     if mask is not None:
         valid = valid & (mask > 0)
     safe_labels = jnp.maximum(labels, 0)
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+    logp = _log_softmax(logits)
     logpt = jnp.take_along_axis(logp, safe_labels[..., None], axis=-1)[..., 0]
     pt = jnp.exp(logpt)
     fl = -jnp.power(1.0 - pt, gamma) * logpt
-    w = valid.astype(jnp.float32)
-    return jnp.sum(fl * w) / jnp.maximum(jnp.sum(w), 1.0)
+    return _masked_mean(fl, valid)
 
 
 def multilabel_bce_loss(
@@ -76,7 +113,8 @@ def prox_penalty(personal_params: PyTree, global_params: PyTree) -> jnp.ndarray:
     w.r.t. it, which is the default when it enters as a closure constant).
     """
     diffs = jax.tree.map(
-        lambda p, g: jnp.sum(jnp.square(p.astype(jnp.float32) - g.astype(jnp.float32))),
+        lambda p, g: _ordered_sum(jnp.square(p.astype(jnp.float32)
+                                             - g.astype(jnp.float32))),
         personal_params,
         global_params,
     )

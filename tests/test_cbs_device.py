@@ -24,6 +24,7 @@ statistically rather than bit-for-bit:
 
 All seeds are fixed: every assertion is deterministic.
 """
+import jax
 import numpy as np
 import pytest
 import scipy.stats
@@ -69,11 +70,9 @@ def _graph(kind: str, seed: int, n: int = 300):
 @pytest.mark.parametrize("kind", KINDS)
 @pytest.mark.parametrize("seed", [0, 1, 2])
 def test_device_probabilities_match_host_1e12(kind, seed):
-    from jax.experimental import enable_x64
-
     indptr, indices, labels, train_idx = _graph(kind, seed)
     p_host = cbs_probabilities(indptr, indices, labels, train_idx)
-    with enable_x64():
+    with jax.enable_x64():
         p_dev = np.asarray(
             cbs_probabilities_device(indptr, indices, labels, train_idx))
     assert p_dev.shape == p_host.shape

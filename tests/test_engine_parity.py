@@ -53,7 +53,6 @@ subprocess enables the persistent compilation cache under ``.jax_cache/``
 so reruns skip compilation entirely.
 """
 import json
-import os
 import subprocess
 import sys
 
@@ -62,10 +61,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _jax_cache import CACHE_PRELUDE, REPO_ROOT
+from _jax_cache import CACHE_PRELUDE, subprocess_env
 
-SUBPROC_ENV = {"PYTHONPATH": os.path.join(REPO_ROOT, "src"),
-               "PATH": "/usr/bin:/bin", "HOME": os.path.expanduser("~")}
+SUBPROC_ENV = subprocess_env()
 
 # --------------------------------------------------------------------------
 # shared harness body (runs inside the test process AND inside subprocesses)
@@ -1073,20 +1071,6 @@ def test_segment_agg_ragged_degree_sweep(kind, seed, mean):
 # pass — ragged sub-ranges incl. the zero-boundary / all-boundary partitions
 # --------------------------------------------------------------------------
 
-def _padded_blocks(blocks):
-    """Pad an EdgeBlocks to >= 1 block (the zero-range case), the same
-    guard engine.stacking applies when stacking split structures."""
-    from repro.kernels.segment_agg import BN, EdgeBlocks
-
-    if blocks.num_blocks:
-        return blocks
-    be = blocks.edges_per_block
-    return EdgeBlocks(
-        num_nodes=0, num_blocks=1, edges_per_block=be,
-        src=np.zeros((1, be), np.int32), local_dst=np.zeros((1, be), np.int32),
-        mask=np.zeros((1, be), np.float32), deg=np.ones((1, BN), np.float32))
-
-
 @pytest.mark.parametrize("split_kind",
                          ["zero_boundary", "all_boundary", "mixed",
                           "unaligned_tail"])
@@ -1114,11 +1098,13 @@ def test_segment_agg_rows_ragged_range_sweep(split_kind, seed, mean):
     indices = rng.integers(0, n, int(indptr[-1])).astype(np.int64)
     x = jnp.asarray(rng.normal(0, 1, (n, 24)).astype(np.float32))
 
-    blocks = _padded_blocks(build_edge_blocks(indptr, indices))
+    # an empty range still yields one (all-pad) block and chunk
+    blocks = build_edge_blocks(indptr, indices)
     msgs = x[jnp.asarray(blocks.src.reshape(-1))]
     got = np.asarray(segment_agg_rows(
         msgs, jnp.asarray(blocks.local_dst), jnp.asarray(blocks.mask),
-        jnp.asarray(blocks.deg), row_base=n_int, num_rows=n, mean=mean))
+        jnp.asarray(blocks.chunk_block), jnp.asarray(blocks.deg),
+        row_base=n_int, num_rows=n, mean=mean))
     want = np.asarray(ref.segment_agg_rows_ref(
         x, jnp.asarray(indices),
         jnp.asarray(np.repeat(np.arange(range_rows), deg)),

@@ -1,9 +1,6 @@
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # hermetic env: deterministic random-sampling shim
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.entropy import label_entropy, partition_entropies, partition_stats
 

@@ -17,10 +17,7 @@ import functools
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # hermetic env: deterministic random-sampling shim
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 import jax
 import jax.numpy as jnp
@@ -39,8 +36,8 @@ from repro.train.optim import AdamW
 P = 4
 
 
-# a plain cached builder, not a pytest fixture: @given-decorated tests
-# cannot take fixtures (the hypothesis shim presents a zero-arg signature)
+# a plain cached builder, not a pytest fixture: hypothesis refuses
+# function-scoped fixtures in @given-decorated tests
 @functools.lru_cache(maxsize=1)
 def _case():
     g = make_benchmark(BENCHMARKS["tiny"])
@@ -69,7 +66,7 @@ def _engine(case, **kw):
 
 # ---------------------------------------------------------------- properties
 
-@settings(max_examples=20)
+@settings(max_examples=20, deadline=None)
 @given(st.floats(0.0, 1.0), st.sampled_from(["degree", "freq"]))
 def test_partition_split_reconstructs_bitwise(hot_frac, policy):
     """Scattering hot + cold tiers into a zero plane reproduces the ragged
@@ -87,7 +84,7 @@ def test_partition_split_reconstructs_bitwise(hot_frac, policy):
         assert np.array_equal(np.sort(rows), np.arange(own_cap))
 
 
-@settings(max_examples=20)
+@settings(max_examples=20, deadline=None)
 @given(st.floats(0.0, 1.0), st.sampled_from(["degree", "freq"]))
 def test_partition_assemble_on_trace_bitwise(hot_frac, policy):
     """The ON-TRACE assembly (what the engine's compiled calls run) is
@@ -103,7 +100,7 @@ def test_partition_assemble_on_trace_bitwise(hot_frac, policy):
         assert (np.asarray(plane) == np.asarray(ref[p])).all()
 
 
-@settings(max_examples=25)
+@settings(max_examples=25, deadline=None)
 @given(st.floats(0.0, 1.0), st.sampled_from(["degree", "freq"]),
        st.lists(st.integers(0, 599), min_size=1, max_size=64),
        st.booleans())
@@ -121,7 +118,7 @@ def test_global_store_gather_bitwise(hot_frac, policy, idx, dup):
     assert (table[gfs.remap[idx]] == direct).all()
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(st.floats(0.0, 1.0), st.sampled_from(["degree", "freq"]))
 def test_global_store_is_permutation(hot_frac, policy):
     g = _case()[0]

@@ -5,10 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # hermetic env: deterministic random-sampling shim
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import partition_graph
 from repro.graph import (BENCHMARKS, GraphSAGE, NeighborSampler,
@@ -101,6 +98,23 @@ def test_sage_full_vs_pallas_segment_agg(tiny):
                     jax.tree_util.tree_leaves(g_ref)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-4, rtol=1e-3)
+
+
+def test_sage_full_pallas_traced_edges_raise(tiny):
+    """Under jit with traced edges and no prebuilt blocks the Pallas path
+    cannot build its block structure: it raises rather than switching to
+    the jnp backend behind the caller's back."""
+    g = tiny
+    model = GraphSAGE(feature_dim=g.feature_dim, hidden_dim=16,
+                      num_classes=g.num_classes)
+    params = model.init(0)
+    src = jnp.asarray(g.indices)
+    dst = jnp.asarray(np.repeat(np.arange(g.num_nodes), np.diff(g.indptr)))
+    feats = jnp.asarray(g.features)
+    fwd = jax.jit(lambda s, d: model.apply_full(params, feats, s, d,
+                                                g.num_nodes))
+    with pytest.raises(ValueError, match="concrete edge lists"):
+        fwd(src, dst)
 
 
 def test_partitioned_graph_invariants(tiny):
@@ -209,17 +223,17 @@ model = GraphSAGE(feature_dim=g.feature_dim, hidden_dim=32, num_classes=g.num_cl
 params = model.init(0)
 r = partition_graph(g.indptr, g.indices, g.features, g.labels, 4, method="ew", seed=0)
 pg = build_partitioned_graph(g, r.parts, 4)
-from repro.launch.mesh import make_mesh_compat
-from repro.engine.compat import shard_map_compat
-mesh = make_mesh_compat((4,), ("data",))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((4,), ("data",), axis_types=(AxisType.Auto,))
 fwd = make_distributed_forward(model, {"max_nodes": pg.max_nodes}, axis_name="data")
 shard = dict(features=pg.features, send_idx=pg.send_idx, send_mask=pg.send_mask,
              recv_pos=pg.recv_pos, edge_src=pg.edge_src, edge_dst=pg.edge_dst,
              edge_mask=pg.edge_mask)
 specs = {k: P("data", *([None]*(v.ndim-1))) for k, v in shard.items()}
-smfwd = jax.jit(shard_map_compat(
+smfwd = jax.jit(jax.shard_map(
     lambda prm, sh: fwd(prm, jax.tree.map(lambda x: x[0], sh)),
-    mesh, in_specs=(P(), specs), out_specs=P("data", None)))
+    mesh=mesh, in_specs=(P(), specs), out_specs=P("data", None),
+    check_vma=False))
 dl = np.asarray(smfwd(params, shard)).reshape(4, pg.max_nodes, g.num_classes)
 src = g.indices; dst = np.repeat(np.arange(g.num_nodes), np.diff(g.indptr))
 full = np.asarray(model.apply_full(params, jnp.asarray(g.features),

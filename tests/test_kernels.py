@@ -1,8 +1,7 @@
-"""Per-kernel correctness: Pallas (interpret=True) vs pure-jnp oracle,
+"""Per-kernel correctness: Pallas (interpreted off a TPU) vs pure-jnp oracle,
 swept over shapes and dtypes — including the custom VJP of the unified
 aggregation op (``segment_mean_op``), whose backward must stage the
 transpose-blocked kernel and match ``jax.grad`` of the jnp reference."""
-import os
 import subprocess
 import sys
 
@@ -11,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _jax_cache import CACHE_PRELUDE, REPO_ROOT
+from _jax_cache import CACHE_PRELUDE, subprocess_env
 from repro.kernels import ops, ref
 
 RNG = np.random.default_rng(11)
@@ -218,13 +217,9 @@ def test_segment_mean_op_fp64_check_grads():
     the fp64 oracle on exact inputs, bitwise grad parity, second-order
     ``check_grads`` on the ragged sweep, row-range sub-ranges and the
     all-pad block."""
-    env = {"PYTHONPATH": os.path.join(REPO_ROOT, "src"),
-           "PATH": "/usr/bin:/bin", "HOME": os.path.expanduser("~")}
-    if "JAX_PLATFORMS" in os.environ:   # e.g. =cpu: skip accelerator probing
-        env["JAX_PLATFORMS"] = os.environ["JAX_PLATFORMS"]
     res = subprocess.run([sys.executable, "-c", FP64_GRAD_SCRIPT],
                          capture_output=True, text=True, timeout=1200,
-                         env=env)
+                         env=subprocess_env())
     assert res.returncode == 0, res.stderr[-3000:]
     assert "FP64_GRAD_OK" in res.stdout
 
@@ -242,12 +237,6 @@ CASES = [
 ]
 
 
-# Root cause of the 14 seed-time failures here: the kernel was written
-# against the newer Pallas API name `pltpu.CompilerParams`, which jax 0.4.x
-# ships as `pltpu.TPUCompilerParams` — every case died with AttributeError
-# before any numerics ran (no tolerance problem; the math was never
-# executed).  kernels/flash_attention.py now resolves whichever name the
-# installed jax provides.
 @pytest.mark.parametrize("case", CASES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_attention_sweep(case, dtype):

@@ -12,9 +12,7 @@ import pytest
 
 def test_sanitize_spec_rules():
     from jax.sharding import PartitionSpec as P
-    from repro.launch.mesh import make_mesh_compat
     from repro.launch.steps import sanitize_spec
-    make_mesh_compat((1,), ("model",))  # mesh construction is version-portable
 
     class FakeMesh:
         shape = {"data": 4, "model": 8, "pod": 2}
@@ -31,7 +29,7 @@ def test_sanitize_spec_rules():
     assert s2[0] == ("pod", "data")
 
 
-from _jax_cache import CACHE_PRELUDE
+from _jax_cache import CACHE_PRELUDE, subprocess_env
 
 # flaky-surface hardening: the cache prelude persists lowered/compiled
 # artifacts under the repo's .jax_cache so repeated runs of this
@@ -44,13 +42,13 @@ SMALL_MESH_SCRIPT = (
 import json
 import jax
 from repro.configs import get_config, SHAPES, InputShape
-from repro.launch.mesh import make_mesh_compat
+from jax.sharding import AxisType
 from repro.launch.steps import build_step
 
 def small_mesh(multi_pod=False):
     shape = (2, 2, 2) if multi_pod else (4, 2)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 results = {}
 cfg = get_config("llama3.2-1b").reduced()
@@ -90,8 +88,7 @@ print("RESULTS", json.dumps(results))
 def test_small_mesh_all_step_kinds_compile():
     res = subprocess.run([sys.executable, "-c", SMALL_MESH_SCRIPT],
                          capture_output=True, text=True, timeout=1800,
-                         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                              "HOME": "/root"})
+                         env=subprocess_env())
     assert res.returncode == 0, res.stderr[-3000:]
     line = [l for l in res.stdout.splitlines() if l.startswith("RESULTS")][0]
     results = json.loads(line[len("RESULTS "):])
@@ -114,3 +111,36 @@ def test_input_specs_all_archs_all_shapes():
                 leaves = [l for l in
                           __import__("jax").tree_util.tree_leaves(spec["caches"])]
                 assert leaves, f"{arch} x {shape.name}: empty cache"
+
+
+CACHE_SCRIPT = r"""
+import jax, jax.numpy as jnp
+from repro.launch.cache import enable_compile_cache
+d = enable_compile_cache()
+jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)
+jax.jit(lambda x: x * 2 + 1)(jnp.ones(3)).block_until_ready()
+print("CACHE", d, jax.config.jax_compilation_cache_dir)
+"""
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_placement(tmp_path, from_env):
+    """Every entry point's cache helper: ``$JAX_COMPILATION_CACHE_DIR`` is
+    used as given (JAX reads it; the helper sets nothing) and receives the
+    entries; unset, the cache goes to ``<checkout>/.jax_cache``."""
+    from _jax_cache import REPO_ROOT
+
+    env = subprocess_env()
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
+    res = subprocess.run([sys.executable, "-c", CACHE_SCRIPT],
+                         capture_output=True, text=True, timeout=600, env=env)
+    assert res.returncode == 0, res.stderr[-3000:]
+    line = [l for l in res.stdout.splitlines() if l.startswith("CACHE")][0]
+    _, used, configured = line.split()
+    want = (str(tmp_path / "cache") if from_env
+            else os.path.join(REPO_ROOT, ".jax_cache"))
+    assert used == configured == want
+    if from_env:
+        assert any((tmp_path / "cache").iterdir()), "no cache entry written"

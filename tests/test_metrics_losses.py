@@ -2,10 +2,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # hermetic env: deterministic random-sampling shim
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.train.losses import cross_entropy_loss, focal_loss, prox_penalty
 from repro.train.metrics import f1_scores, f1_scores_jnp

@@ -1,10 +1,7 @@
 import numpy as np
 import pytest
 import scipy.sparse as sp
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # hermetic env: deterministic random-sampling shim
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.partition import assign_edge_weights, metis_kway, partition_graph
 from repro.core.partition.api import METHODS

@@ -29,10 +29,9 @@ import sys
 import numpy as np
 import pytest
 
-from _jax_cache import CACHE_PRELUDE, REPO_ROOT
+from _jax_cache import CACHE_PRELUDE, subprocess_env
 
-SUBPROC_ENV = {"PYTHONPATH": os.path.join(REPO_ROOT, "src"),
-               "PATH": "/usr/bin:/bin", "HOME": os.path.expanduser("~")}
+SUBPROC_ENV = subprocess_env()
 
 
 # --------------------------------------------------------------------------

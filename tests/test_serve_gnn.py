@@ -18,7 +18,6 @@
 5. AOT cache-key stability: re-evaluating with FRESH identically-sharded
    arrays must not recompile (``compile_count`` regression).
 """
-import os
 import subprocess
 import sys
 
@@ -27,10 +26,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _jax_cache import CACHE_PRELUDE, REPO_ROOT
+from _jax_cache import CACHE_PRELUDE, REPO_ROOT, subprocess_env
 
-SUBPROC_ENV = {"PYTHONPATH": os.path.join(REPO_ROOT, "src"),
-               "PATH": "/usr/bin:/bin", "HOME": os.path.expanduser("~")}
+SUBPROC_ENV = subprocess_env()
 
 
 # --------------------------------------------------------------------------
@@ -232,7 +230,7 @@ def test_pallas_recompute_path_matches_ref(served):
     outs = []
     for pallas in (False, True):
         srv = GNNServingEngine(model, prm, pg, export,
-                               use_pallas_agg=pallas, interpret=True)
+                               use_pallas_agg=pallas)
         for gid, vec in upd.items():
             srv.update_features(gid, vec)
         srv.flush()
