@@ -146,9 +146,8 @@ def report_run(tag: str, res) -> None:
     dev = ", ".join(f"{t:.4f}" for t in res.epoch_device_s)
     print(f"  {tag}: engine {res.engine_mode}, epochs {res.epochs_run} "
           f"(GP from epoch {res.personalize_start_epoch}, "
-          f"{res.phase1_epochs} phase-1), compile {res.compile_s:.1f}s, "
-          f"per-epoch device s [{dev}], test micro-F1 "
-          f"{res.f1.micro:.4f}", flush=True)
+          f"{res.phase1_epochs} phase-1), per-epoch device s [{dev}], "
+          f"test micro-F1 {res.f1.micro:.4f}", flush=True)
 
 
 def training_phase(graph) -> None:

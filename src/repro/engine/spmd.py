@@ -31,6 +31,28 @@ GraphSAGE's full-graph mean aggregation routes through the Pallas
 ``segment_agg`` kernel (``use_pallas_agg=True``, compiled on a TPU and
 interpreted elsewhere) or, with ``use_pallas_agg=False``, through the jnp
 segment-op reference.
+
+Host spans.  The program marks its host-side layer boundaries with
+``jax.profiler.TraceAnnotation``; they cost about a microsecond each with
+the profiler off and land in its trace, on the device ops' clock, when it
+is on.  These are all of them (``<program>`` is the name ``_compiled``
+receives; the compiled module of that program reads ``jit_eat_<program>``
+with ``-`` spelled ``_``):
+
+  eat.draw               one whole host epoch draw (``_EpochPrefetcher``'s
+                         worker); metadata ``batches`` (iterations x
+                         partitions) and ``bytes`` (the stacked epoch)
+  eat.draw.cbs           the samplers' ``batches()`` calls
+  eat.draw.make_batch    one ``make_batch(nodes)`` call; its self time is the
+                         padding, labels and host-to-device copies
+  eat.draw.neighbors     ``NeighborSampler.sample``
+  eat.draw.gather        ``SampledBlocks.feature_views``
+  eat.draw.stack         stacking a draw's batches, per iteration and for
+                         the epoch
+  eat.draw_wait          the main thread waiting for the worker's draw
+  eat.dispatch/<program> enqueuing a compiled program
+  eat.wait/<program>     blocking until that program's outputs are ready
+  eat.compile/<program>  tracing, lowering and compiling it (a cache miss)
 """
 from __future__ import annotations
 
@@ -40,6 +62,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.gp.trainer import (GPHyperParams, GRAD_COMPRESS_MODES,
@@ -140,7 +163,8 @@ def stack_epoch_batches(samplers, make_batch: Callable, num_parts: int):
     """
     import time
 
-    host_batches = [s.batches() for s in samplers]
+    with TraceAnnotation("eat.draw.cbs"):
+        host_batches = [s.batches() for s in samplers]
     iters = max(len(b) for b in host_batches)
     t_host = np.zeros(num_parts)
     rows = []
@@ -150,10 +174,14 @@ def stack_epoch_batches(samplers, make_batch: Callable, num_parts: int):
             hb = host_batches[p]
             nodes = hb[it % len(hb)]
             t0 = time.perf_counter()
-            per_p.append(make_batch(nodes))
+            with TraceAnnotation("eat.draw.make_batch"):
+                per_p.append(make_batch(nodes))
             t_host[p] += time.perf_counter() - t0
-        rows.append(stack_pytrees(per_p))          # (P, ...)
-    return stack_pytrees(rows), t_host, iters      # (iters, P, ...)
+        with TraceAnnotation("eat.draw.stack"):
+            rows.append(stack_pytrees(per_p))      # (P, ...)
+    with TraceAnnotation("eat.draw.stack"):
+        batches = stack_pytrees(rows)              # (iters, P, ...)
+    return batches, t_host, iters
 
 
 class SPMDEngine:
@@ -365,7 +393,6 @@ class SPMDEngine:
                                        # separately-compiled evaluate() call
         self._cache: dict = {}
         self.compile_count = 0
-        self.compile_seconds = 0.0     # trace + lower + compile, all calls
 
     # ------------------------------------------------------------ plumbing
     @property
@@ -403,13 +430,15 @@ class SPMDEngine:
         ARGUMENT, never as a closed-over constant, which would be baked into
         each program (one device copy per executable, and on a mesh a full
         copy on every device).  ``fn`` reads it through ``shards`` /
-        ``labels`` / ``masks``, bound to that argument while it traces."""
+        ``labels`` / ``masks``, bound to that argument while it traces.
+
+        The returned call opens ``eat.dispatch/<name>`` around the enqueue
+        and carries ``name`` as ``.program`` for :meth:`_timed`."""
         key = self._shape_key(name, args)
         if key not in self._cache:
-            import time
+            import re
 
             self.compile_count += 1
-            t0 = time.perf_counter()
 
             def with_resident(resident, *a):
                 saved, self._resident = self._resident, resident
@@ -418,11 +447,20 @@ class SPMDEngine:
                 finally:
                     self._resident = saved
 
-            self._cache[key] = jax.jit(with_resident).lower(
-                self._resident, *args).compile()
-            self.compile_seconds += time.perf_counter() - t0
+            # the compiled module, and so the device trace, reads
+            # jit_eat_<name>
+            with_resident.__name__ = "eat_" + re.sub(r"\W", "_", name)
+            with TraceAnnotation(f"eat.compile/{name}"):
+                self._cache[key] = jax.jit(with_resident).lower(
+                    self._resident, *args).compile()
         exe = self._cache[key]
-        return lambda *a: exe(self._resident, *a)
+
+        def run(*a):
+            with TraceAnnotation(f"eat.dispatch/{name}"):
+                return exe(self._resident, *a)
+
+        run.program = name
+        return run
 
     def _micro_of(self, preds, labels, mask):
         lab = jnp.where(mask, labels, -1)
@@ -1161,11 +1199,14 @@ class SPMDEngine:
     # outside every timed window.
 
     def _timed(self, fn, *args):
+        """Call a :meth:`_compiled` program and wait for its outputs
+        (``eat.wait/<program>``); returns them and the seconds taken."""
         import time
 
         t0 = time.perf_counter()
         out = fn(*args)
-        jax.block_until_ready(out)
+        with TraceAnnotation(f"eat.wait/{fn.program}"):
+            jax.block_until_ready(out)
         return out, time.perf_counter() - t0
 
     def phase0_epoch(self, params, opt_state, batches):

@@ -16,6 +16,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .csr import CSRGraph
 
@@ -34,9 +35,10 @@ class SampledBlocks:
         """Gather features: x_t (B,D), x_1 (B,F1,D), x_2 (B,F1,F2,D)."""
         b, f1 = self.nbrs1.shape
         f2 = self.nbrs2.shape[1]
-        x_t = features[self.targets]
-        x_1 = features[self.nbrs1.reshape(-1)].reshape(b, f1, -1)
-        x_2 = features[self.nbrs2.reshape(-1)].reshape(b, f1, f2, -1)
+        with TraceAnnotation("eat.draw.gather"):
+            x_t = features[self.targets]
+            x_1 = features[self.nbrs1.reshape(-1)].reshape(b, f1, -1)
+            x_2 = features[self.nbrs2.reshape(-1)].reshape(b, f1, f2, -1)
         return x_t, x_1, x_2
 
 
@@ -63,6 +65,7 @@ class NeighborSampler:
     def sample(self, targets: np.ndarray) -> SampledBlocks:
         targets = np.asarray(targets, dtype=np.int64)
         f1, f2 = self.fanouts
-        nbrs1 = self._sample_neighbors(targets, f1)
-        nbrs2 = self._sample_neighbors(nbrs1.reshape(-1), f2)
+        with TraceAnnotation("eat.draw.neighbors"):
+            nbrs1 = self._sample_neighbors(targets, f1)
+            nbrs2 = self._sample_neighbors(nbrs1.reshape(-1), f2)
         return SampledBlocks(targets=targets, nbrs1=nbrs1, nbrs2=nbrs2)

@@ -67,6 +67,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .core import (GPController, GPHyperParams, GPScheduleConfig,
                    broadcast_to_partitions, partition_graph)
@@ -241,8 +242,6 @@ class EATResult:
     # per-epoch device seconds of the epoch's compiled train call (both
     # phases, block_until_ready-timed, compilation excluded)
     epoch_device_s: list[float] = field(default_factory=list)
-    # engine trace + lower + compile seconds over the whole run
-    compile_s: float = 0.0
 
     def summary(self) -> dict:
         return {
@@ -322,6 +321,10 @@ class _EpochPrefetcher:
     last handed-out epoch consumed — the stream position an epoch-boundary
     checkpoint must store for a resumed run to re-draw the next epoch
     identically (DESIGN.md §10).
+
+    ``draw`` returns a tuple whose first item is the epoch's stacked
+    ``(iters, P, ...)`` batch pytree, as :func:`stack_epoch_batches` does;
+    the worker's ``eat.draw`` span carries its ``batches`` and ``bytes``.
     """
 
     def __init__(self, draw, snapshot=None):
@@ -339,7 +342,12 @@ class _EpochPrefetcher:
 
         def work():
             try:
-                box["out"] = self._draw()
+                with TraceAnnotation("eat.draw") as span:
+                    box["out"] = self._draw()
+                    leaves = jax.tree_util.tree_leaves(box["out"][0])
+                    span.set_metadata(
+                        batches=leaves[0].shape[0] * leaves[0].shape[1],
+                        bytes=sum(x.nbytes for x in leaves))
             except BaseException as e:   # surfaces in next(), not swallowed
                 box["err"] = e
 
@@ -353,7 +361,8 @@ class _EpochPrefetcher:
         if self._pending is None:
             self._spawn()
         th, box = self._pending
-        th.join()
+        with TraceAnnotation("eat.draw_wait"):
+            th.join()
         if "err" in box:
             raise box["err"]
         self._spawn()
@@ -996,5 +1005,4 @@ def run_eat_distgnn(cfg: EATConfig, verbose: bool = False,
         resumed_from_epoch=resumed_from,
         straggler_delay_s=straggler_total,
         epoch_device_s=epoch_dev,
-        compile_s=float(getattr(engine, "compile_seconds", 0.0)),
     )
