@@ -44,9 +44,16 @@ with ``-`` spelled ``_``):
                          partitions) and ``bytes`` (the stacked epoch)
   eat.draw.cbs           the samplers' ``batches()`` calls
   eat.draw.make_batch    one ``make_batch(nodes)`` call; its self time is the
-                         padding, labels and host-to-device copies
+                         padding, labels and the copies of those to the
+                         device (no longer the rows' copy where the device
+                         gathers them)
   eat.draw.neighbors     ``NeighborSampler.sample``
-  eat.draw.gather        ``SampledBlocks.feature_views``
+  eat.draw.gather        ``SampledBlocks.feature_views``: on the device
+                         (enqueuing ``jit_eat_gather`` with the int32 ids)
+                         from the sampler's staged copy of its own table,
+                         in numpy for any other table; metadata ``rows``
+                         (rows gathered) and ``device`` (1 on the device,
+                         0 in numpy)
   eat.draw.stack         stacking a draw's batches, per iteration and for
                          the epoch
   eat.draw_wait          the main thread waiting for the worker's draw

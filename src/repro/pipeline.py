@@ -214,8 +214,9 @@ class EATResult:
     # wall-clock claim's machine-load-independent proxy)
     phase0_iter_history: list[int] = field(default_factory=list)
     # TOTAL host→device payload across all phase-0 epochs: stacked batch
-    # arrays on the host-sampled path, just the (P, 2) PRNG keys per epoch
-    # on the async path (divide by epochs for the per-epoch payload) —
+    # arrays on the host-sampled path (the batch's bytes, also where its
+    # rows were gathered on the device), just the (P, 2) PRNG keys per
+    # epoch on the async path (divide by epochs for the per-epoch payload) —
     # plus, under the feature store, the cold rows staged for phase-0's
     # compiled calls (train gathers and the per-epoch validation eval)
     host_to_device_bytes_phase0: int = 0
@@ -452,7 +453,10 @@ def run_eat_distgnn(cfg: EATConfig, verbose: bool = False,
               f"{pg.summary()}")
 
     # ---------------- per-host samplers -----------------------------------
-    neigh = NeighborSampler(graph, fanouts=cfg.fanouts, seed=cfg.seed)
+    # the feature store keeps the whole table off the device: its batches
+    # gather on the host; otherwise make_batch gathers on the device
+    neigh = NeighborSampler(graph, fanouts=cfg.fanouts, seed=cfg.seed,
+                            stage_features=not cfg.feat_store)
     host_train = [graph.train_idx[parts[graph.train_idx] == p]
                   for p in range(n_parts)]
     samplers = [
@@ -491,6 +495,8 @@ def run_eat_distgnn(cfg: EATConfig, verbose: bool = False,
         return model.num_layers * int(getattr(
             engine, "halo_wire_bytes_per_layer", pg.halo_bytes_per_layer))
 
+    # graph.features itself where fdt is its dtype: feature_views then
+    # gathers on the device from the sampler's staged copy
     batch_feats = np.asarray(graph.features, fdt)
 
     def make_batch(nodes: np.ndarray) -> dict:
