@@ -58,9 +58,9 @@ def control_numbers(cell, seed: int) -> dict:
         _, spec = run.checked_epochs(trainer)
     finally:
         trainer.close()
-    cfg = cell.spec.config
-    low = Reference(cfg, cell.graph, cell.parts, dtype=jnp.bfloat16)
-    ref = Reference(cfg, cell.graph, cell.parts, dtype=jnp.float32)
+    cfg, model = cell.spec.config, cell.spec.model
+    low = Reference(cfg, model, cell.graph, cell.parts, dtype=jnp.bfloat16)
+    ref = Reference(cfg, model, cell.graph, cell.parts, dtype=jnp.float32)
     rows = cell.val_ids
     return check.compare(low.run(layers0, spec, eval_rows=rows),
                          ref.run(layers0, spec, eval_rows=rows),
@@ -97,6 +97,9 @@ def main(argv=None) -> int:
         t0 = time.perf_counter()
         out["program"][s] = program_numbers(cell, s)
         emit("program", "", s, out["program"][s], t0)
+    # per chip (stderr): the program's peak; the first chip's holds the
+    # reference's too
+    run.peak_bytes(cell.devices)
     for s in args.control_seeds:
         t0 = time.perf_counter()
         out["control"][s] = control_numbers(cell, s)
@@ -106,7 +109,7 @@ def main(argv=None) -> int:
     for fault in harness.FAULTS if args.fault_seeds else ():
         seeds = args.fault_seeds[:1] if fault == "frozen_state" \
             else args.fault_seeds
-        fcell = harness.Cell(spec, faults=(fault,))
+        fcell = harness.Cell(spec, faults=(fault,), like=cell)
         out["faults"][fault] = {}
         for s in seeds:
             t0 = time.perf_counter()

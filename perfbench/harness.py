@@ -2,24 +2,28 @@
 and driven through the program's own per-epoch calls.
 
 A cell is ``BENCHMARK.json``'s workload entry: a configuration file (graph
-and model sizes, under ``configs/``) and a traffic file (the job: sampled
-mini-batches or full-graph steps, partitions, fanout and batch, under
-``traffic/``).  Nothing here names a cell: a later cell adds files only.
+and model sizes, under ``configs/``), the model module its ``arch`` names
+(``models/<arch>.py``: weights, the program's model, the plain forward,
+FLOPs) and a traffic file (the job: sampled mini-batches or full-graph
+steps, partitions, fanout and batch, under ``traffic/``).  Nothing here
+names a cell or a model: a later cell adds files only.
 
 ``Cell`` holds what does not depend on ``--seed``: the generated graph, its
-EW partition, the program's ``SPMDEngine`` and its compiled programs.
-``Trainer`` holds what does: the initial weights (made here, on the device,
-from the seed), the samplers and the optimizer state.  One ``Trainer.epoch``
-makes the same calls ``repro.pipeline.run_eat_distgnn`` makes for a phase-0
-epoch:
+EW partition, the program's ``SPMDEngine`` and its compiled programs, on
+the cell's ``chips`` devices (one: every partition vmapped on
+``jax.devices()[0]``; more: one partition per chip under ``shard_map``).
+``Trainer`` holds what does: the initial weights (made by the model
+module, on the device, from the seed), the samplers and the optimizer
+state.  One ``Trainer.epoch`` makes the same calls
+``repro.pipeline.run_eat_distgnn`` makes for a phase-0 epoch:
 
   sampled     per-partition ``CBSampler`` + ``NeighborSampler`` draws,
               stacked by ``stack_epoch_batches`` behind the pipeline's
               double-buffered ``_EpochPrefetcher``, then
               ``SPMDEngine.phase0_epoch`` (train scan + validation eval);
   fullgraph   ``SPMDEngine.phase0_fullgraph_epoch`` (full-batch steps
-              through the halo exchange and ``segment_agg``, then the
-              validation eval).
+              through the halo exchange and the aggregation kernel, then
+              the validation eval).
 
 The checked epochs (the first few, through the same calls) also keep the
 validation forward's predictions of every validation node, which
@@ -35,13 +39,15 @@ import json
 import time
 from dataclasses import dataclass, field
 from pathlib import Path
+from types import ModuleType
 
 import numpy as np
 
-from . import graphgen
+from . import byname, graphgen
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
+MODELS = BENCH / "models"
 
 # faults of the timed path a training cell can have (tests, calibration)
 FAULTS = ("frozen_state", "half_batch", "no_exchange", "altered_rows")
@@ -58,12 +64,15 @@ class CellSpec:
     chips: int
     config: dict
     traffic: dict
+    model: ModuleType             # models/<arch>.py
 
 
-def load_cell(workload: str, root: Path = ROOT) -> CellSpec:
+def load_cell(workload: str, root: Path = ROOT,
+              models: Path = MODELS) -> CellSpec:
     """Find a workload of ``<root>/BENCHMARK.json`` and read its
-    configuration file and its traffic file (``perfbench/traffic/<name>``
-    with any of the data suffixes)."""
+    configuration file, its traffic file (``perfbench/traffic/<name>``)
+    and the model module its configuration's ``arch`` names
+    (``<models>/<arch>.py``)."""
     spec = load_json(Path(root) / "BENCHMARK.json")
     cells = {w["name"]: w for w in spec["workloads"]}
     if workload not in cells:
@@ -73,8 +82,11 @@ def load_cell(workload: str, root: Path = ROOT) -> CellSpec:
     cfg_entry = {c["name"]: c for c in spec["configs"]}[w["config"]]
     config = load_json(Path(root) / cfg_entry["file"])
     traffic = load_json(traffic_file(w["traffic"], root))
+    if "arch" not in config:
+        raise KeyError(f"configuration {cfg_entry['file']} names no arch")
     return CellSpec(name=workload, chips=int(w["chips"]), config=config,
-                    traffic=traffic)
+                    traffic=traffic,
+                    model=byname.load("model", config["arch"], models))
 
 
 def traffic_file(name: str, root: Path = ROOT) -> Path:
@@ -84,31 +96,7 @@ def traffic_file(name: str, root: Path = ROOT) -> Path:
     return hits[0]
 
 
-# ---------------------------------------------------------------- weights
-def init_params(config: dict, seed: int):
-    """GraphSAGE weights from the seed, made on the device in one jitted
-    call: per layer (w_self, w_neigh) Glorot-uniform and a zero bias.
-    Returns a list of per-layer dicts of float32 device arrays."""
-    import jax
-    import jax.numpy as jnp
-
-    dims = layer_dims(config)
-
-    @jax.jit
-    def make(key):
-        out = []
-        for i, (d_in, d_out) in enumerate(zip(dims[:-1], dims[1:])):
-            k1, k2 = jax.random.split(jax.random.fold_in(key, i))
-            lim = float(np.sqrt(6.0 / (d_in + d_out)))
-            u = lambda k: jax.random.uniform(k, (d_in, d_out), jnp.float32,
-                                             -lim, lim)
-            out.append({"w_self": u(k1), "w_neigh": u(k2),
-                        "b": jnp.zeros((d_out,), jnp.float32)})
-        return out
-
-    return make(seed_key(seed))
-
-
+# ---------------------------------------------------------------- seeds
 def seed_key(seed: int):
     """A PRNG key from a seed of any size (seeds may exceed 32 bits)."""
     import jax
@@ -118,34 +106,20 @@ def seed_key(seed: int):
     return jax.random.fold_in(key, (seed >> 31) % (1 << 31))
 
 
-def layer_dims(config: dict) -> tuple[int, ...]:
-    n = int(config["num_layers"])
-    return ((int(config["feature_dim"]),) + (int(config["hidden_dim"]),)
-            * (n - 1) + (int(config["num_classes"]),))
-
-
-def to_program_params(layers):
-    from repro.graph.sage import SAGELayer, SAGEParams
-
-    return SAGEParams(layers=tuple(
-        SAGELayer(w_self=l["w_self"], w_neigh=l["w_neigh"], b=l["b"])
-        for l in layers))
-
-
-def from_program_params(params) -> list[dict]:
-    return [{"w_self": np.asarray(l.w_self), "w_neigh": np.asarray(l.w_neigh),
-             "b": np.asarray(l.b)} for l in params.layers]
-
-
 # ---------------------------------------------------------------- the cell
 class Cell:
     """Graph, partition and engine of one configuration under one traffic
-    mix; independent of ``--seed``, so calibration reuses it across seeds."""
+    mix; independent of ``--seed``, so calibration reuses it across seeds.
+    ``like``, a cell of the same spec, lends its graph and partition (the
+    calibration's fault cells), so only the engine is built anew."""
 
-    def __init__(self, spec: CellSpec, faults: tuple[str, ...] = ()):
+    def __init__(self, spec: CellSpec, faults: tuple[str, ...] = (),
+                 like: "Cell | None" = None):
+        import jax
+
         from repro.core import GPHyperParams, partition_graph
         from repro.engine import EngineConfig, make_engine
-        from repro.graph import CSRGraph, GraphSAGE, build_partitioned_graph
+        from repro.graph import CSRGraph, build_partitioned_graph
         from repro.train.optim import AdamW
 
         self.spec, self.faults = spec, tuple(faults)
@@ -153,37 +127,46 @@ class Cell:
         self.kind = tr["kind"]
         if self.kind not in ("sampled", "fullgraph"):
             raise ValueError(f"unknown traffic kind {self.kind!r}")
+        self.num_parts = int(tr["partition"]["num_parts"])
+        if spec.chips not in (1, self.num_parts):
+            raise ValueError(
+                f"a cell runs its {self.num_parts} partitions on one chip or "
+                f"one to a chip, not on {spec.chips}")
+        # the chips the engine runs on: partition p on devices[p] under
+        # shard_map, or every partition on devices[0], vmapped
+        self.devices = jax.devices()[:spec.chips]
+        mode = "stacked" if spec.chips == 1 else "spmd"
         t0 = time.perf_counter()
-        self.graph = graphgen.generate(cfg)
+        if like is not None:
+            self.graph, self.prog_graph = like.graph, like.prog_graph
+            self.parts, self.pg = like.parts, like.pg
+            t1 = t2 = t0
+        else:
+            g = self.graph = graphgen.generate(cfg)
+            self.prog_graph = CSRGraph(
+                indptr=g.indptr, indices=g.indices, features=g.features,
+                labels=g.labels, train_idx=g.train_idx, val_idx=g.val_idx,
+                test_idx=g.test_idx, num_classes=g.num_classes,
+                name=cfg["name"])
+            t1 = time.perf_counter()
+            part = tr["partition"]
+            pres = partition_graph(
+                g.indptr, g.indices, g.features, g.labels, self.num_parts,
+                method=part["method"], seed=int(part["seed"]),
+                fanout_k=int(part["fanout_k"]))
+            self.parts = np.asarray(pres.parts)
+            t2 = time.perf_counter()
+            self.pg = build_partitioned_graph(self.prog_graph, self.parts,
+                                              self.num_parts)
         g = self.graph
-        self.prog_graph = CSRGraph(
-            indptr=g.indptr, indices=g.indices, features=g.features,
-            labels=g.labels, train_idx=g.train_idx, val_idx=g.val_idx,
-            test_idx=g.test_idx, num_classes=g.num_classes, name=cfg["name"])
-        t1 = time.perf_counter()
-        part = tr["partition"]
-        self.num_parts = int(part["num_parts"])
-        pres = partition_graph(
-            g.indptr, g.indices, g.features, g.labels, self.num_parts,
-            method=part["method"], seed=int(part["seed"]),
-            fanout_k=int(part["fanout_k"]))
-        self.parts = np.asarray(pres.parts)
-        t2 = time.perf_counter()
-        self.pg = build_partitioned_graph(self.prog_graph, self.parts,
-                                          self.num_parts)
-        self.model = GraphSAGE(feature_dim=int(cfg["feature_dim"]),
-                               hidden_dim=int(cfg["hidden_dim"]),
-                               num_classes=int(cfg["num_classes"]),
-                               num_layers=int(cfg["num_layers"]))
+        self.model, loss_fn = spec.model.build_program(cfg)
         self.opt = AdamW(lr=float(cfg["lr"]), b1=float(cfg["adam_b1"]),
                          b2=float(cfg["adam_b2"]), eps=float(cfg["adam_eps"]),
                          grad_clip=float(cfg["grad_clip"]))
-        loss_fn = self.model.make_loss_fn("ce")
         self._exchange_patch = self._patch_exchange()
         self.engine = make_engine(
             self.model, loss_fn, self.opt, self.pg, hp=GPHyperParams(),
-            config=EngineConfig(mode=tr.get("engine", "auto"),
-                                use_pallas_agg=True))
+            config=EngineConfig(mode=mode, use_pallas_agg=True))
         self._plant_engine_faults()
         self._span_evaluate()
         t3 = time.perf_counter()
@@ -303,8 +286,10 @@ class Trainer:
         from repro.pipeline import _EpochPrefetcher
 
         self.cell, self.seed = cell, int(seed)
-        self.params0 = init_params(cell.spec.config, self.seed)
-        self.params = to_program_params(self.params0)
+        model = cell.spec.model
+        self.params0 = model.init_params(cell.spec.config,
+                                         seed_key(self.seed))
+        self.params = model.to_program_params(self.params0)
         self.opt_state = cell.opt.init(self.params)
         self._record_left = int(record_epochs)
         self._evals_left = int(record_epochs)

@@ -1,10 +1,11 @@
-"""Plain reference of what a cell's timed steps compute: 2-layer GraphSAGE
-with the mean aggregator (Hamilton et al. 2017, Eq. 1-2), softmax
-cross-entropy, the mean of the partitions' gradients, global-norm clipping
-and AdamW, written in straightforward ``jax.numpy``.
+"""Plain reference of what a cell's timed steps compute: the model's
+forward (its module's ``sampled_logits``, ``full_logits``,
+``rows_logits``), softmax cross-entropy, the mean of the partitions'
+gradients, global-norm clipping and AdamW, written in straightforward
+``jax.numpy``.
 
 Imports nothing of the program and takes nothing it made: the weights come
-from the benchmark's own initializer, the features and labels from the
+from the model module's own initializer, the features and labels from the
 benchmark's generator, and the inputs of each step are node ids (the
 sampled batches) or the partition of each node (full-graph steps).
 
@@ -12,10 +13,12 @@ sampled batches) or the partition of each node (full-graph steps).
 dtype (``bfloat16``: the control) casts every array to it and computes
 there, matmuls at the chip's native precision.
 
-Full-graph steps aggregate over the whole graph at once; the partitioned
+Full-graph steps aggregate over the whole graph; the partitioned
 program's exchange of halo rows each layer makes its owned rows equal to
 this.  Partition p's loss is the mean over its own training nodes, and the
-step's gradient is the mean of the partitions' gradients.
+step's gradient is the mean of the partitions' gradients.  Every
+aggregation runs over the edges in fixed-size blocks (:class:`Edges`), so
+the reference's memory does not grow with the edge count.
 
 After each epoch the validation forward is the same whole-graph forward,
 its last layer taken over the validation nodes alone.
@@ -23,49 +26,58 @@ its last layer taken over the validation nodes alone.
 from __future__ import annotations
 
 import contextlib
+from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["Reference"]
+__all__ = ["EDGE_BLOCK", "Edges", "Reference", "edge_blocks"]
+
+# edges per aggregation block: 512 MiB of gathered float32 rows at width 256
+EDGE_BLOCK = 1 << 19
 
 
-def _layer(lp, h_self, h_neigh, last: bool):
-    out = h_self @ lp["w_self"] + h_neigh @ lp["w_neigh"] + lp["b"]
-    return out if last else jax.nn.relu(out)
+def edge_blocks(src: np.ndarray, dst: np.ndarray, num_out: int,
+                block: int = EDGE_BLOCK) -> tuple[np.ndarray, np.ndarray]:
+    """Edges ``src[e] -> dst[e]`` (destinations sorted) as ``(blocks, B)``
+    int32 arrays, ``B = min(block, E)``; the last block is padded with
+    edges into ``num_out``, a destination the sums drop."""
+    e = len(src)
+    b = max(1, min(int(block), e))
+    n = -(-e // b) * b
+    s = np.zeros(n, np.int32)
+    d = np.full(n, num_out, np.int32)
+    s[:e], d[:e] = src, dst
+    return s.reshape(-1, b), d.reshape(-1, b)
 
 
-def sampled_logits(layers, x_t, x_1, x_2):
-    """Targets (B, D), their sampled neighbours (B, F1, D) and those
-    neighbours' samples (B, F1, F2, D) -> (B, C)."""
-    l1, l2 = layers
-    h_t = _layer(l1, x_t, x_1.mean(axis=1), last=False)
-    h_1 = _layer(l1, x_1, x_2.mean(axis=2), last=False)
-    return _layer(l2, h_t, h_1.mean(axis=1), last=True)
+@dataclass(frozen=True)
+class Edges:
+    """In-edges of ``num_out`` destinations in blocks (:func:`edge_blocks`)
+    and each destination's inverse degree, inside a traced function."""
 
+    src: jax.Array                # (blocks, B)
+    dst: jax.Array                # (blocks, B), sorted; num_out = padding
+    num_out: int
+    inv_deg: jax.Array            # (num_out,)
 
-def full_logits(layers, feats, src, dst, inv_deg):
-    """Every node's logits; edge e carries src[e] -> dst[e]."""
-    h = feats
-    for i, lp in enumerate(layers):
-        agg = jax.ops.segment_sum(h[src], dst, num_segments=h.shape[0])
-        h = _layer(lp, h, agg * inv_deg[:, None], last=i == len(layers) - 1)
-    return h
+    def sum(self, message):
+        """Per destination the sum of ``message(src, dst)`` ((B, W) rows
+        of one block's edges) over its in-edges: a scan over the blocks
+        that scatter-adds each block's messages into the running sum (as
+        ``segment_sum`` does into zeros), the block's messages recomputed
+        in the backward pass (``jax.checkpoint``) rather than kept."""
+        msg = jax.checkpoint(message)
+        out = jax.eval_shape(msg, self.src[0], self.dst[0])
 
+        def add(acc, block):
+            s, d = block
+            return acc.at[d].add(msg(s, d), indices_are_sorted=True,
+                                 mode="drop"), None
 
-def rows_logits(layers, feats, src, dst, inv_deg, rows, last_src, last_pos):
-    """Logits of the nodes ``rows``: every layer but the last over the
-    whole graph, the last over ``rows`` alone, whose in-edges are
-    ``last_src[e] -> rows[last_pos[e]]``."""
-    h = feats
-    for lp in layers[:-1]:
-        agg = jax.ops.segment_sum(h[src], dst, num_segments=h.shape[0],
-                                  indices_are_sorted=True)
-        h = _layer(lp, h, agg * inv_deg[:, None], last=False)
-    agg = jax.ops.segment_sum(h[last_src], last_pos, num_segments=rows.shape[0],
-                              indices_are_sorted=True)
-    return _layer(layers[-1], h[rows], agg * inv_deg[rows][:, None], last=True)
+        acc = jnp.zeros((self.num_out,) + out.shape[1:], out.dtype)
+        return jax.lax.scan(add, acc, (self.src, self.dst))[0]
 
 
 def masked_ce(logits, labels, mask):
@@ -77,10 +89,13 @@ def masked_ce(logits, labels, mask):
 
 
 class Reference:
-    """Steps of one cell from given weights, in ``dtype``."""
+    """Steps of one cell from given weights, in ``dtype``, with the
+    forward of ``model`` (the cell's model module)."""
 
-    def __init__(self, config: dict, graph, parts: np.ndarray, dtype=jnp.float32):
-        self.cfg, self.graph = config, graph
+    def __init__(self, config: dict, model, graph, parts: np.ndarray,
+                 dtype=jnp.float32, edge_block: int = EDGE_BLOCK):
+        self.cfg, self.model, self.graph = config, model, graph
+        self.edge_block = int(edge_block)
         self.parts = np.asarray(parts)
         self.num_parts = int(self.parts.max()) + 1
         self.dtype = jnp.dtype(dtype)
@@ -156,7 +171,7 @@ class Reference:
     def _sampled_step_fn(self):
         def losses_of(layers, x):
             per = jax.vmap(lambda xt, x1, x2, y, m: masked_ce(
-                sampled_logits(layers, xt, x1, x2), y, m))(
+                self.model.sampled_logits(layers, xt, x1, x2), y, m))(
                 x["x_t"], x["x_1"], x["x_2"], x["labels"], x["mask"])
             return per.sum() / per.shape[0], per
 
@@ -178,8 +193,8 @@ class Reference:
             train = np.zeros(n, bool)
             train[g.train_idx] = True
             train &= g.labels >= 0
-            self._full = {"src": g.indices.astype(np.int32),
-                          "dst": dst.astype(np.int32),
+            src, dst = edge_blocks(g.indices, dst, n, self.edge_block)
+            self._full = {"src": src, "dst": dst,
                           "inv_deg": 1.0 / deg,
                           "feats": g.features,
                           "labels": np.maximum(g.labels, 0),
@@ -189,8 +204,9 @@ class Reference:
 
     def _full_step_fn(self):
         def losses_of(layers, x):
-            logits = full_logits(layers, x["feats"], x["src"], x["dst"],
-                                 x["inv_deg"])
+            edges = Edges(x["src"], x["dst"], x["feats"].shape[0],
+                          x["inv_deg"])
+            logits = self.model.full_logits(layers, x["feats"], edges)
             per = jax.vmap(lambda m: masked_ce(logits, x["labels"], m))(
                 x["masks"])
             return per.sum() / per.shape[0], per
@@ -209,24 +225,34 @@ class Reference:
         g = self.graph
         rows = np.asarray(rows, np.int64)
         deg = np.diff(g.indptr)[rows]
-        x["last_src"] = np.concatenate(
+        last_src = np.concatenate(
             [g.indices[g.indptr[r]:g.indptr[r + 1]] for r in rows]
             ).astype(np.int32) if len(rows) else np.zeros(0, np.int32)
-        x["last_pos"] = np.repeat(np.arange(len(rows)), deg).astype(np.int32)
+        last_pos = np.repeat(np.arange(len(rows)), deg).astype(np.int32)
+        x["last_src"], x["last_dst"] = edge_blocks(
+            last_src, last_pos, len(rows), self.edge_block)
         x["rows"] = rows.astype(np.int32)
         x.pop("labels"), x.pop("masks")
         return x
 
+    def _eval_fn(self):
+        def logits(layers, x):
+            n, rows = x["feats"].shape[0], x["rows"]
+            edges = Edges(x["src"], x["dst"], n, x["inv_deg"])
+            last = Edges(x["last_src"], x["last_dst"], rows.shape[0],
+                         x["inv_deg"][rows])
+            return self.model.rows_logits(layers, x["feats"], edges, rows,
+                                          last)
+
+        return self._steps.setdefault("eval", jax.jit(logits))
+
     def eval_logits(self, layers, rows: np.ndarray) -> np.ndarray:
         """``(len(rows), C)`` float32 logits of the nodes ``rows``."""
-        key = ("eval", len(rows))
+        key = ("eval_inputs", len(rows))
         if key not in self._steps:
-            self._steps[key] = (jax.jit(rows_logits), self.cast(
-                self._eval_inputs(rows)))
-        fn, x = self._steps[key]
-        return np.asarray(fn(layers, x["feats"], x["src"], x["dst"],
-                             x["inv_deg"], x["rows"], x["last_src"],
-                             x["last_pos"]), np.float32)
+            self._steps[key] = self.cast(self._eval_inputs(rows))
+        return np.asarray(self._eval_fn()(layers, self._steps[key]),
+                          np.float32)
 
     # -- the checked run -------------------------------------------------
     def run(self, layers0, epochs: list, eval_rows=None) -> dict:

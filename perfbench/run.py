@@ -137,8 +137,7 @@ def host_tree(tree):
 def checked_epochs(trainer):
     """The first epochs, through the window's own call: the set-up's
     warm-up, and the steps the reference follows."""
-    from perfbench.harness import from_program_params
-
+    from_program_params = trainer.cell.spec.model.from_program_params
     outs = [trainer.epoch()]
     mu1 = from_program_params(trainer.opt_state.mu)
     outs += [trainer.epoch() for _ in range(CHECKED_EPOCHS - 1)]
@@ -157,26 +156,33 @@ def reference_numbers(cell, layers0, prog, spec, dtype=None):
     from perfbench import check
     from perfbench.reference import Reference
 
-    ref = Reference(cell.spec.config, cell.graph, cell.parts,
+    ref = Reference(cell.spec.config, cell.spec.model, cell.graph, cell.parts,
                     dtype=dtype or jnp.float32)
     out = ref.run(layers0, spec, eval_rows=cell.val_ids)
     return check.compare(prog, out, layers0, float(cell.spec.config["adam_b1"]))
 
 
+def placement(cell) -> dict[int, list[int]]:
+    """Device id -> the partitions it holds: all on the one chip, or
+    partition p on the cell's p-th chip."""
+    if len(cell.devices) == 1:
+        return {cell.devices[0].id: list(range(cell.num_parts))}
+    return {d.id: [p] for p, d in enumerate(cell.devices)}
+
+
 def layer_context(cell, spec, epochs, tr, info, peaks):
     from perfbench.readers import Context
 
-    from perfbench.harness import layer_dims
-
     pg = cell.pg
     edges = [int((pg.edge_mask[p] > 0).sum()) for p in range(pg.num_parts)]
+    used = placement(cell)
     return Context(
-        trace=tr, dev=tr.fullest_device(), chips=spec.chips,
+        trace=tr, dev=tr.fullest_device(among=used), chips=spec.chips,
         peaks=peaks.get(info["kind"], {}), kind=cell.kind,
-        dims=layer_dims(spec.config),
+        dims=spec.model.layer_dims(spec.config),
         fanouts=tuple(spec.traffic.get("fanouts", ())), epochs=epochs,
         owned=[int(x) for x in pg.n_own], halo=[int(x) for x in pg.n_halo],
-        edges=edges)
+        edges=edges, model=spec.model, placement=used)
 
 
 def main(argv=None, *, require_chip: bool = True, faults=(),
@@ -220,8 +226,7 @@ def main(argv=None, *, require_chip: bool = True, faults=(),
         epochs, elapsed = run_window(trainer, args.seconds)
         tr = None
     counter.active = False
-    used = jax.devices()[:spec.chips]
-    info["memory_peak_bytes"] = peak_bytes(used)
+    info["memory_peak_bytes"] = peak_bytes(cell.devices)
 
     nodes = sum(e.nodes for e in epochs)
     attempted = len(epochs)
@@ -232,8 +237,8 @@ def main(argv=None, *, require_chip: bool = True, faults=(),
         entries = [m for m in bench["per_layer"]
                    if args.workload in m.get("workloads", [args.workload])]
         metrics = readers.read_all(entries, ctx)
-        busy = [tr.busy_ns(d) for d in sorted(tr.devices)][:spec.chips]
-        info["busy_s"] = (sum(busy) / len(busy) * 1e-9) if busy else 0.0
+        busy = [tr.busy_ns(d.id) for d in cell.devices]
+        info["busy_s"] = sum(busy) / len(busy) * 1e-9
         info["window_s"] = tr.window_ns * 1e-9
         if ctx.dev is not None:
             breakdown = {"device_ops": tr.top_ops(ctx.dev),

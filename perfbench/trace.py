@@ -14,13 +14,17 @@ import re
 import shutil
 import tempfile
 from dataclasses import dataclass, field
+from pathlib import Path
 
-__all__ = ["OTHER_KERNEL", "SEGMENT_AGG", "Trace", "capture", "from_xspace",
-           "union_ns"]
+from . import byname
+
+__all__ = ["KERNELS", "OTHER_KERNEL", "Trace", "capture", "from_xspace",
+           "kernel_tag", "load_kernels", "short_name", "tagged", "union_ns"]
 
 WINDOW_SPAN = "bench.window"
-# tags short_name gives a Pallas kernel's op: segment_agg, or any other
-SEGMENT_AGG = " [segment_agg]"
+# the kernel files: one per Pallas kernel the benchmark knows
+KERNELS = Path(__file__).resolve().parent / "kernels"
+# the tag short_name gives a Pallas op that no kernel file claims
 OTHER_KERNEL = " [pallas]"
 _DEVICE_PLANE = re.compile(r"^/device:(TPU|GPU):(\d+)$")
 # the line of a device plane that holds one event per operation executed
@@ -53,10 +57,12 @@ class Trace:
         """Summed time of the operations whose name ``match`` accepts."""
         return sum(e - s for _, s, e in self._clipped(dev, match))
 
-    def fullest_device(self) -> int | None:
-        if not self.devices:
-            return None
-        return max(sorted(self.devices), key=self.busy_ns)
+    def fullest_device(self, among=None) -> int | None:
+        """The device with the most busy time, of ``among`` (device ids)
+        where given."""
+        devs = sorted(d for d in self.devices
+                      if among is None or d in among)
+        return max(devs, key=self.busy_ns) if devs else None
 
     def top_ops(self, dev: int, top: int = 10) -> list[list]:
         total: dict[str, int] = {}
@@ -119,6 +125,7 @@ def from_xspace(profile) -> Trace:
     devices: dict[int, list] = {}
     host: list = []
     window = None
+    kernels = load_kernels()
     for plane in profile.planes:
         m = _DEVICE_PLANE.match(plane.name)
         if m:
@@ -132,7 +139,8 @@ def from_xspace(profile) -> Trace:
             for name in _OP_LINES:
                 for ev in (lines[name].events if name in lines else ()):
                     s = int(ev.start_ns)
-                    ops.append((_in_module(modules, s) + short_name(ev.name),
+                    ops.append((_in_module(modules, s)
+                                + short_name(ev.name, kernels),
                                 s, int(ev.start_ns + ev.duration_ns)))
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
@@ -146,11 +154,23 @@ def from_xspace(profile) -> Trace:
     return Trace(window=window, devices=devices, host=host)
 
 
-def short_name(text: str) -> str:
+def tagged(op: str, kernel: str) -> bool:
+    """Whether a traced op's name carries the tag of ``kernel``."""
+    return op.endswith(f" [{kernel}]")
+
+
+def load_kernels(root: Path = KERNELS) -> dict:
+    """``{name: module}`` of the kernel files, in the order they claim an
+    op."""
+    return byname.load_all("kernel", root)
+
+
+def short_name(text: str, kernels: dict | None = None) -> str:
     """``%fusion.3 fusion f32[6614528,256]`` from an op's HLO text: the
     instruction, its opcode and its result shape without layouts.  A
-    Pallas kernel's op is tagged: ``[segment_agg]`` where its operands are
-    that kernel's 128-edge chunk layout, ``[pallas]`` otherwise."""
+    Pallas kernel's op is tagged ``[<name>]`` by the first kernel file
+    (``kernels``, else all of them) whose ``matches`` accepts its HLO text,
+    ``[pallas]`` where none does."""
     if " = " not in text:
         return text
     instr, rest = text.split(" = ", 1)
@@ -161,10 +181,10 @@ def short_name(text: str) -> str:
     shape = rest[:m.start()].strip()
     if len(shape) > 60:
         shape = shape[:57] + "..."
-    return f"{instr} {m.group(1)} {shape}{kernel_tag(instr, rest)}"
+    return f"{instr} {m.group(1)} {shape}{kernel_tag(instr, rest, kernels)}"
 
 
-def kernel_tag(instr: str, rest: str) -> str:
+def kernel_tag(instr: str, rest: str, kernels: dict | None = None) -> str:
     """The tag of a Pallas kernel's op, from its instruction name and its
     HLO text without layouts; ``""`` for any other op.  On a TPU the
     kernel is a ``tpu_custom_call`` custom call, or, where XLA fuses it, a
@@ -174,15 +194,15 @@ def kernel_tag(instr: str, rest: str) -> str:
               or (instr.startswith("%closed_call") and "kind=kCustom" in rest))
     if not pallas:
         return ""
-    return SEGMENT_AGG if _CHUNKS.search(rest) else OTHER_KERNEL
+    for name, mod in (load_kernels() if kernels is None
+                      else kernels).items():
+        if mod.matches(rest):
+            return f" [{name}]"
+    return OTHER_KERNEL
 
 
 _LAYOUT = re.compile(r"\{[^{}]*\}")
 _OPCODE = re.compile(r"(?:^|[\s)])([a-z][\w\-]*)\(")
-# segment_agg's operands: its chunk->row-block map s32[C], then per chunk
-# 128 edge ids s32[C,1,128] and 128 edge weights f32[C,1,128]
-_CHUNKS = re.compile(r"s32\[(\d+)\] %[\w.\-]+, s32\[\1,1,128\] %[\w.\-]+, "
-                     r"(?:f32|bf16)\[\1,1,128\] %")
 
 
 def _module_name(name: str) -> str:

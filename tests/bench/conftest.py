@@ -2,7 +2,8 @@
 
 ``tiny_root`` is a checkout-like directory holding a ``BENCHMARK.json``
 whose cells carry the real cells' names (so the real limits of
-``perfbench/limits`` apply) on a 600-node graph."""
+``perfbench/limits`` apply) on a 600-node graph.  The four-chip cell runs
+in a child process that asks for four host devices."""
 import json
 import shutil
 import sys
@@ -19,14 +20,17 @@ TINY_TRAFFIC = {
     "tiny_sampled": {"kind": "sampled",
                      "partition": {"num_parts": 4, "method": "ew",
                                    "fanout_k": 5, "seed": 0},
-                     "engine": "auto", "fanouts": [5, 3], "batch_size": 32,
+                     "fanouts": [5, 3], "batch_size": 32,
                      "subset_fraction": 0.25, "class_balanced": True},
     "tiny_full": {"kind": "fullgraph",
                   "partition": {"num_parts": 4, "method": "ew",
                                 "fanout_k": 5, "seed": 0},
-                  "engine": "auto", "full_graph_iters": 1},
+                  "full_graph_iters": 1},
 }
-CELLS = {"products-sampled": "tiny_sampled", "flickr-fullgraph": "tiny_full"}
+CELLS = {"products-sampled": "tiny_sampled", "flickr-fullgraph": "tiny_full",
+         "products-x4-fullgraph": "tiny_full"}
+# cells on more than one chip; run only where JAX has that many devices
+CHIPS = {"products-x4-fullgraph": 4}
 
 
 @pytest.fixture(scope="session")
@@ -44,7 +48,7 @@ def tiny_root(tmp_path_factory) -> Path:
                          "file": "perfbench/configs/tiny.json",
                          "reduced": [], "why": "tests"}]
     bench["workloads"] = [{"name": w, "config": "tiny", "traffic": t,
-                           "chips": 1, "why": "tests"}
+                           "chips": CHIPS.get(w, 1), "why": "tests"}
                           for w, t in CELLS.items()]
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
     return root

@@ -105,11 +105,40 @@ def test_short_op_names(text, short):
     (KERNEL, " [segment_agg]"), (FUSED_KERNEL, " [segment_agg]"),
     (GATHER, ""), (OTHER, " [pallas]")])
 def test_kernel_ops_recognised(text, tag):
-    from perfbench.readers import is_segment_agg
-    from perfbench.trace import short_name
+    from perfbench import byname
+    from perfbench.trace import KERNELS, short_name
+
+    is_segment_agg = byname.load("kernel", "segment_agg",
+                                 KERNELS).is_segment_agg
 
     name = short_name(text)
     tags = (" [segment_agg]", " [pallas]")
     assert name.endswith(tag) if tag else not name.endswith(tags)
     assert is_segment_agg("with_resident#029719/" + name) is (
         tag == " [segment_agg]")
+
+
+def test_halo_a2a_share_reads_the_fullest_chips_all_to_all():
+    from perfbench.readers import Context, load_reader
+
+    def ctx(t, dev):
+        return Context(trace=t, dev=dev, chips=2, peaks={}, kind="fullgraph",
+                       dims=(1, 1), fanouts=(), epochs=[], owned=[1, 1],
+                       halo=[0, 0], edges=[0, 0])
+
+    read = load_reader("halo.a2a_share")
+    t = trace()
+    # device 0: the all-to-all runs 50..60 ms of the 100 ms window
+    assert read(ctx(t, 0)) == pytest.approx(10.0)
+    # as the trace prints an op: program, instruction, opcode, shape; an
+    # async pair counts both halves, and an op after the window nothing
+    t.devices[0] += [
+        ("p#1/%all-to-all-start.2 all-to-all-start (f32[4,9,256])",
+         62 * MS, 64 * MS),
+        ("p#1/%all-to-all-done.2 all-to-all-done f32[4,9,256]",
+         64 * MS, 65 * MS),
+        ("p#1/%fusion.7 fusion f32[4,9,256]", 66 * MS, 70 * MS),
+        ("p#1/%all-to-all.4 all-to-all f32[4,9,256]", 120 * MS, 130 * MS)]
+    assert read(ctx(t, 0)) == pytest.approx(13.0)
+    # device 1 ran no exchange (as one chip's vmapped copy): nothing read
+    assert read(ctx(t, 1)) is None
