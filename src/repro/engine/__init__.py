@@ -1,11 +1,12 @@
 from .spmd import AXIS, EngineConfig, SPMDEngine, stack_epoch_batches
 from .sequential import SequentialReference
-from .stacking import (StackedBlocks, build_stacked_split_vjp_blocks,
+from .stacking import (StackedBlocks, build_stacked_gat_blocks,
+                       build_stacked_split_vjp_blocks,
                        build_stacked_vjp_blocks, stack_pytrees)
 
 __all__ = [
     "AXIS", "EngineConfig", "SPMDEngine", "SequentialReference",
-    "StackedBlocks", "build_stacked_vjp_blocks",
+    "StackedBlocks", "build_stacked_vjp_blocks", "build_stacked_gat_blocks",
     "build_stacked_split_vjp_blocks", "stack_pytrees",
     "stack_epoch_batches", "make_engine",
 ]

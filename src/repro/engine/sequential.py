@@ -39,6 +39,11 @@ class SequentialReference:
 
     def __init__(self, model, loss_fn, optimizer, pg: PartitionedGraph,
                  hp: GPHyperParams = GPHyperParams(), config=None):
+        if model.aggregation != "mean":
+            raise ValueError(
+                f"engine mode 'sequential' writes GraphSAGE's mean layer "
+                f"out op for op; {type(model).__name__} runs on the stacked "
+                f"or spmd engine")
         f = config.dtype if config is not None else jnp.float32
         self.model = model
         self.loss_fn = loss_fn

@@ -84,8 +84,9 @@ from ..graph.distributed import (HALO_COMPRESS_MODES, PartitionedGraph,
                                  halo_refresh_plan,
                                  make_cached_forward, make_distributed_forward,
                                  make_export_forward,
-                                 make_overlap_forward, make_pallas_mean_agg,
-                                 make_pallas_split_agg, make_ref_mean_agg,
+                                 make_overlap_forward, make_pallas_attn_agg,
+                                 make_pallas_mean_agg, make_pallas_split_agg,
+                                 make_ref_attn_agg, make_ref_mean_agg,
                                  make_ref_split_agg, wire_row_bytes)
 from ..graph.featstore import (assemble_features, check_feat_budget,
                                feat_peak_bytes, reconstruct_features)
@@ -93,6 +94,7 @@ from ..train.metrics import f1_scores_jnp
 from ..train.optim import apply_updates
 from .stacking import (build_stacked_feat_store, build_stacked_halo_cache,
                        build_stacked_halo_residual,
+                       build_stacked_gat_blocks,
                        build_stacked_split_vjp_blocks,
                        build_stacked_vjp_blocks, stack_pytrees)
 
@@ -293,6 +295,9 @@ class SPMDEngine:
             shards["features"] = jnp.asarray(pg.features, f)
         check_feat_budget(config.feat_budget_mb, self._feat_peak_bytes(pg),
                           context=f"mode={self.mode}")
+        # GAT's attention: the transpose reads alpha through a slot map
+        attention = model.aggregation == "attention"
+
         def _as_blk(d: dict) -> dict:
             # one nested pytree per segment_mean_op call site: int arrays
             # stay int32, float structure follows the feature dtype
@@ -322,7 +327,9 @@ class SPMDEngine:
                 "edge_mask": jnp.asarray(pg.edge_mask, f),
             })
             if config.use_pallas_agg:
-                shards["blk"] = _as_blk(build_stacked_vjp_blocks(pg))
+                shards["blk"] = _as_blk(
+                    build_stacked_gat_blocks(pg) if attention
+                    else build_stacked_vjp_blocks(pg))
         self._mesh = None
         if self.mode == "spmd":
             from ..launch.mesh import make_partition_mesh
@@ -351,8 +358,14 @@ class SPMDEngine:
                 model, meta, axis_name=AXIS, agg_interior=aggs[0],
                 agg_boundary=aggs[1], ring_chunks=config.ring_chunks)
         else:
-            agg = (make_pallas_mean_agg(pg.max_nodes)
-                   if config.use_pallas_agg else make_ref_mean_agg(pg.max_nodes))
+            if attention:
+                agg = (make_pallas_attn_agg(pg.max_nodes)
+                       if config.use_pallas_agg
+                       else make_ref_attn_agg(pg.max_nodes))
+            else:
+                agg = (make_pallas_mean_agg(pg.max_nodes)
+                       if config.use_pallas_agg
+                       else make_ref_mean_agg(pg.max_nodes))
             self._mean_agg = agg
             self.fwd = make_distributed_forward(model, meta, axis_name=AXIS,
                                                 agg=agg)
@@ -385,6 +398,9 @@ class SPMDEngine:
                 build_stacked_halo_cache(pg, model.layer_input_dims))
             self._halo_age = 0
             self._cached_fwds: dict = {}
+            # the full refresh every cache starts with; refuses a model the
+            # cached spelling cannot run, here rather than at the first eval
+            self._cached_fwd(0, self.max_send)
         # fault injection (DESIGN.md §10): when armed, the next eval
         # forward's freshly exchanged cache payload is "lost in transit" —
         # the stale cache is kept and ages on
@@ -1216,7 +1232,17 @@ class SPMDEngine:
             jax.block_until_ready(out)
         return out, time.perf_counter() - t0
 
+    def _needs_sampled_loss(self, spelling: str) -> None:
+        """Refuse a sampled-batch program where the model has no sampled
+        loss (GAT: the engine is built with ``loss_fn=None``)."""
+        if self.loss_fn is None:
+            raise ValueError(
+                f"{spelling} trains on sampled mini-batches; "
+                f"{type(self.model).__name__} has no sampled loss and trains "
+                f"through phase0_fullgraph_epoch only")
+
     def phase0_epoch(self, params, opt_state, batches):
+        self._needs_sampled_loss("phase0_epoch")
         impl = self._phase0_spmd if self.mode == "spmd" else self._phase0_stacked
         if self.grad_compress == "topk":
             res = self._grad_residual(params)
@@ -1250,6 +1276,7 @@ class SPMDEngine:
         eval (it is part of the one device call; the pipeline's epoch-time
         attribution accounts for that).
         """
+        self._needs_sampled_loss("phase0_epoch_async")
         if self._device_sampler is None:
             raise ValueError("phase0_epoch_async needs set_device_sampler()")
         if self.config.feat_groups:
@@ -1344,6 +1371,7 @@ class SPMDEngine:
         return b.astype(jnp.int32)
 
     def phase1_epoch(self, pparams, popt, batches, global_params, budgets):
+        self._needs_sampled_loss("phase1_epoch")
         iters = jax.tree_util.tree_leaves(batches)[0].shape[0]
         budgets = self._as_budgets(budgets, iters)
         impl = self._phase1_spmd if self.mode == "spmd" else self._phase1_stacked
@@ -1386,6 +1414,7 @@ class SPMDEngine:
         recompiles to log2(I) shapes), so converged partitions stop paying
         for the stragglers' full epochs.
         """
+        self._needs_sampled_loss("phase1_epoch_async")
         if self._device_sampler is None:
             raise ValueError("phase1_epoch_async needs set_device_sampler()")
         budgets = self._as_budgets(budgets, self._device_sampler.num_batches)

@@ -21,6 +21,7 @@ from ..kernels.segment_agg import (BEC, BN, build_edge_blocks,
                                    build_transpose_blocks)
 
 __all__ = ["StackedBlocks", "build_stacked_vjp_blocks",
+           "build_stacked_gat_blocks",
            "build_stacked_split_vjp_blocks", "build_stacked_feat_store",
            "build_stacked_halo_cache", "build_stacked_halo_residual",
            "stack_pytrees"]
@@ -180,6 +181,23 @@ def build_stacked_vjp_blocks(pg: PartitionedGraph, bn: int = BN,
             pg.edge_src[p][real], pg.edge_dst[p][real], pg.max_nodes,
             bn=bn, bec=bec))
     return _stack_vjp_dict(fwds, bwds, pg.num_parts, bn)
+
+
+def build_stacked_gat_blocks(pg: PartitionedGraph) -> dict:
+    """:func:`build_stacked_vjp_blocks` plus the attention's slot map
+    ``t_slot`` (P, T_t, BEC): per transpose chunk slot, the forward slot of
+    the same edge, whose alpha the transpose reads (GAT only; pad slots
+    point one past the forward layout, at a zero weight)."""
+    from ..kernels.gat_agg import transpose_slot_map
+
+    out = build_stacked_vjp_blocks(pg)
+    real = pg.edge_mask > 0
+    out["t_slot"] = np.stack([
+        transpose_slot_map(pg.edge_src[p][real[p]], pg.edge_dst[p][real[p]],
+                           pg.max_nodes, pg.max_nodes, out["t_src"].shape[1],
+                           pad=out["src"][0].size)
+        for p in range(pg.num_parts)])
+    return out
 
 
 def build_stacked_split_vjp_blocks(pg: PartitionedGraph, bn: int = BN,

@@ -49,7 +49,8 @@ __all__ = ["PartitionedGraph", "build_partitioned_graph", "make_distributed_forw
            "HALO_COMPRESS_MODES", "quantize_rows", "dequantize_rows",
            "wire_row_bytes",
            "make_ref_mean_agg", "make_pallas_mean_agg",
-           "make_ref_split_agg", "make_pallas_split_agg"]
+           "make_ref_split_agg", "make_pallas_split_agg",
+           "make_ref_attn_agg", "make_pallas_attn_agg"]
 
 
 @dataclass
@@ -543,14 +544,61 @@ def make_pallas_split_agg(own_cap: int):
     return agg_interior, agg_boundary
 
 
+def make_ref_attn_agg(max_nodes: int):
+    """jnp segment-op attention aggregation over a shard's flat local edge
+    list (GAT's ``use_pallas_agg=False`` backend): ``agg(z, s_src, s_dst,
+    shard) -> (max_nodes, K * F)``, the edge softmax over each row's
+    in-edges and itself, and the weighted sum."""
+    from ..kernels.gat_agg import attention_agg_ref
+
+    def attn_agg(z, s_src, s_dst, shard):
+        return attention_agg_ref(z, s_src, s_dst, shard["edge_src"],
+                                 shard["edge_dst"], shard["edge_mask"],
+                                 max_nodes)
+
+    return attn_agg
+
+
+def make_pallas_attn_agg(max_nodes: int):
+    """Pallas attention aggregation (GAT): the edge softmax on the chunk
+    layout of ``shard["blk"]`` (``engine.stacking.build_stacked_gat_blocks``,
+    which carries the transpose's slot map) and the ``gat_agg`` kernel,
+    whose VJP runs the transpose and the ``gat_sddmm`` kernel."""
+    from ..kernels.gat_agg import attention_agg_op
+
+    def attn_agg(z, s_src, s_dst, shard):
+        return attention_agg_op(z, s_src, s_dst, shard["blk"],
+                                num_rows=max_nodes).astype(z.dtype)
+
+    return attn_agg
+
+
+def _mean_models_only(model, spelling: str) -> None:
+    """Refuse a model whose layers are not GraphSAGE's mean aggregation in
+    a forward spelling that writes that layer out itself."""
+    if model.aggregation != "mean":
+        raise ValueError(
+            f"{spelling} runs GraphSAGE's mean-aggregation layer only; "
+            f"{type(model).__name__} runs on the plain distributed forward "
+            f"(make_distributed_forward without compression)")
+
+
 # ---------------------------------------------------------------------------
 # SPMD forwards
 # ---------------------------------------------------------------------------
 
-def make_distributed_forward(model: GraphSAGE, pg_meta: dict,
+def make_distributed_forward(model, pg_meta: dict,
                              axis_name: str = "data", agg=None,
                              compress: str = "none", ring_chunks: int = 0):
     """Build the per-shard n-layer SYNCHRONOUS forward with halo exchange.
+
+    The forward owns the loop and each layer's halo exchange; the model
+    supplies each layer's arithmetic, ``model.layer(lp, h, shard, agg, i,
+    last)`` on the post-exchange rows ``h``: GraphSAGE's mean layer
+    (``h @ w_self + agg(h) @ w_neigh + b``) or GAT's project, score,
+    normalise and aggregate.  ``agg`` is the model's aggregation backend
+    (the neighbour mean ``agg(h, shard)``, or for GAT the attention
+    ``agg(z, s_src, s_dst, shard)``).
 
     Returns ``fwd(params, shard) -> logits`` where ``shard`` is the
     per-partition slice of the stacked PartitionedGraph arrays; call it
@@ -559,9 +607,10 @@ def make_distributed_forward(model: GraphSAGE, pg_meta: dict,
     (jax batches ``all_to_all`` across the vmapped axis with the same
     transpose semantics — see DESIGN.md §3).
 
-    ``agg(h, shard) -> (max_nodes, D)`` selects the aggregation backend;
-    default is the jnp segment-op reference, the SPMD engine passes
-    :func:`make_pallas_mean_agg` to put the Pallas kernel on the hot path.
+    ``agg(h, shard) -> (max_nodes, D)`` selects GraphSAGE's aggregation
+    backend; default is the jnp segment-op reference, the SPMD engine passes
+    :func:`make_pallas_mean_agg` to put the Pallas kernel on the hot path
+    (for GAT, :func:`make_pallas_attn_agg` or :func:`make_ref_attn_agg`).
 
     ``compress`` (DESIGN.md §11): ``"none"`` returns EXACTLY the forward
     above — the same closure, no extra arguments, so compression off is
@@ -571,28 +620,31 @@ def make_distributed_forward(model: GraphSAGE, pg_meta: dict,
     ``residual["r{i}"]`` is layer i's carried send-side quantization error
     (same (P, maxS, D_i) geometry as the send lists); ``ring_chunks``
     selects the exchange schedule for the quantized payloads (the
-    uncompressed forward keeps its all_to_all spelling untouched).
+    uncompressed forward keeps its all_to_all spelling untouched).  The
+    compressed spelling writes GraphSAGE's layer out and refuses any other
+    model.
 
     Every layer's exchange fully serialises before any aggregation — the
     baseline :func:`make_overlap_forward` is benchmarked against.
     """
     max_nodes = pg_meta["max_nodes"]
-    mean_agg = agg if agg is not None else make_ref_mean_agg(max_nodes)
+    if agg is None:
+        agg = (make_ref_attn_agg if model.aggregation == "attention"
+               else make_ref_mean_agg)(max_nodes)
 
     if compress == "none":
-        def fwd(params: SAGEParams, shard: dict) -> jnp.ndarray:
+        def fwd(params, shard: dict) -> jnp.ndarray:
             h = shard["features"]
             last = len(params.layers) - 1
             for i, lp in enumerate(params.layers):
                 h = _halo_exchange(h, shard["send_idx"], shard["send_mask"],
                                    shard["recv_pos"], axis_name)
-                a = mean_agg(h, shard)
-                h = h @ lp.w_self + a @ lp.w_neigh + lp.b
-                if i < last:
-                    h = jax.nn.relu(h)
+                h = model.layer(lp, h, shard, agg, i, i == last)
             return h
 
         return fwd
+
+    _mean_models_only(model, f"the compressed forward ({compress!r})")
 
     def fwd_c(params: SAGEParams, shard: dict, residual: dict):
         h = shard["features"]
@@ -606,7 +658,7 @@ def make_distributed_forward(model: GraphSAGE, pg_meta: dict,
                 ring_chunks, h.dtype)
             h = h.at[shard["recv_pos"].reshape(-1)].set(
                 recv.reshape(-1, h.shape[-1]).astype(h.dtype))
-            a = mean_agg(h, shard)
+            a = agg(h, shard)
             h = h @ lp.w_self + a @ lp.w_neigh + lp.b
             if i < last:
                 h = jax.nn.relu(h)
@@ -651,6 +703,7 @@ def make_cached_forward(model: GraphSAGE, pg_meta: dict,
     new_residual)``.  ``compress == "none"`` keeps today's closure and
     signature bit-for-bit.
     """
+    _mean_models_only(model, "the cached forward (halo_cache)")
     max_nodes = pg_meta["max_nodes"]
     mean_agg = agg if agg is not None else make_ref_mean_agg(max_nodes)
     lo = int(refresh_lo)
@@ -740,6 +793,7 @@ def make_overlap_forward(model: GraphSAGE, pg_meta: dict,
     carries nothing, the boundary ranges are empty, and the per-row select
     resolves entirely to the interior half.
     """
+    _mean_models_only(model, "the overlapped forward (overlap_halo)")
     max_nodes = pg_meta["max_nodes"]
     own_cap = pg_meta["own_cap"]
     if agg_interior is None or agg_boundary is None:
@@ -794,6 +848,7 @@ def make_export_forward(model: GraphSAGE, pg_meta: dict,
     written, so the serving engine lands its halo rows from the same PR-6
     cache geometry (``recv_pos`` slots) the training eval path uses.
     """
+    _mean_models_only(model, "the export forward (export_serving_state)")
     max_nodes = pg_meta["max_nodes"]
     mean_agg = agg if agg is not None else make_ref_mean_agg(max_nodes)
 

@@ -74,6 +74,9 @@ class GraphSAGE:
     l2_normalize: bool = False
     dropout: float = 0.0  # applied to inputs of each layer when training
 
+    # the engine's aggregation structures for this model: the mean kernel's
+    aggregation = "mean"
+
     @property
     def layer_dims(self) -> tuple[int, ...]:
         """Per-layer (input, ..., output) widths: (D, H, ..., H, C)."""
@@ -112,6 +115,16 @@ class GraphSAGE:
             if self.l2_normalize:
                 out = out / jnp.maximum(jnp.linalg.norm(out, axis=-1, keepdims=True), 1e-9)
         return out
+
+    def layer(self, lp: SAGELayer, h: jnp.ndarray, shard: dict, agg, i: int,
+              last: bool) -> jnp.ndarray:
+        """Layer ``i`` of the distributed full-graph forward over a
+        partition's rows: ``agg(h, shard)`` is the neighbour mean."""
+        a = agg(h, shard)
+        h = h @ lp.w_self + a @ lp.w_neigh + lp.b
+        if not last:
+            h = jax.nn.relu(h)
+        return h
 
     def _maybe_dropout(self, x: jnp.ndarray, key) -> jnp.ndarray:
         if self.dropout <= 0.0 or key is None:

@@ -42,7 +42,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["EdgeBlocks", "build_edge_blocks", "build_edge_blocks_from_edges",
-           "build_transpose_blocks", "build_vjp_blocks", "default_interpret",
+           "build_transpose_blocks", "build_vjp_blocks", "count_pallas_call",
+           "default_interpret", "edge_slots",
            "segment_agg_blocks", "segment_agg_rows", "segment_agg_bwd_blocks",
            "segment_mean_op", "pallas_call_count", "interpreted_call_count",
            "reset_pallas_call_count"]
@@ -73,6 +74,14 @@ def reset_pallas_call_count() -> None:
     _INTERPRETED_CALLS = 0
 
 
+def count_pallas_call(interpret: bool) -> None:
+    """Record one Pallas kernel staged into a jaxpr (every kernel of the
+    package counts here)."""
+    global _PALLAS_CALLS, _INTERPRETED_CALLS
+    _PALLAS_CALLS += 1
+    _INTERPRETED_CALLS += bool(interpret)
+
+
 def default_interpret() -> bool:
     """Pallas interpret mode, derived from the backend: compiled on a TPU,
     interpreted (the kernel body run as plain XLA ops) everywhere else."""
@@ -96,8 +105,9 @@ class EdgeBlocks:
     deg: np.ndarray            # (nb, BN) float32 (>=1 where real)
 
 
-def build_edge_blocks(indptr: np.ndarray, indices: np.ndarray, bn: int = BN,
-                      bec: int = BEC) -> EdgeBlocks:
+def _chunk_layout(indptr: np.ndarray, bn: int, bec: int):
+    """The ragged layout of a CSR: ``(chunks per node block, total chunks,
+    each CSR edge's slot in the flat (T * bec) chunk layout)``."""
     indptr = np.asarray(indptr, dtype=np.int64)
     n = len(indptr) - 1
     nb = max(1, (n + bn - 1) // bn)
@@ -105,11 +115,33 @@ def build_edge_blocks(indptr: np.ndarray, indices: np.ndarray, bn: int = BN,
     hi = indptr[np.minimum((np.arange(nb) + 1) * bn, n)]
     chunks = np.maximum(1, (hi - lo + bec - 1) // bec)
     first = np.cumsum(chunks) - chunks            # first chunk of each block
-    t = int(chunks.sum())
+    blk = np.repeat(np.arange(n), np.diff(indptr)) // bn
+    slot = first[blk] * bec + (np.arange(len(blk)) - lo[blk])
+    return chunks, int(chunks.sum()), slot
+
+
+def edge_slots(src: np.ndarray, dst: np.ndarray, num_rows: int,
+               bn: int = BN, bec: int = BEC) -> np.ndarray:
+    """Slot of each edge ``src[e] -> dst[e]`` in the flat chunk layout that
+    :func:`build_edge_blocks_from_edges` gives the same edge list."""
+    dst = np.asarray(dst, dtype=np.int64)
+    order = np.argsort(dst, kind="stable")
+    indptr = np.zeros(num_rows + 1, dtype=np.int64)
+    np.cumsum(np.bincount(dst, minlength=num_rows)[:num_rows], out=indptr[1:])
+    slot = np.empty(len(dst), dtype=np.int64)
+    slot[order] = _chunk_layout(indptr, bn, bec)[2]
+    return slot
+
+
+def build_edge_blocks(indptr: np.ndarray, indices: np.ndarray, bn: int = BN,
+                      bec: int = BEC) -> EdgeBlocks:
+    indptr = np.asarray(indptr, dtype=np.int64)
+    n = len(indptr) - 1
+    nb = max(1, (n + bn - 1) // bn)
+    chunks, t, slot = _chunk_layout(indptr, bn, bec)
 
     dst = np.repeat(np.arange(n), np.diff(indptr))
     blk = dst // bn
-    slot = first[blk] * bec + (np.arange(len(dst)) - lo[blk])
     src = np.zeros(t * bec, dtype=np.int32)
     ldst = np.zeros(t * bec, dtype=np.int32)
     mask = np.zeros(t * bec, dtype=np.float32)
@@ -221,11 +253,9 @@ def segment_agg_blocks(
     ``interpret`` defaults to :func:`default_interpret`; passing ``False``
     asks for the TPU lowering (e.g. to compile for a described chip).
     """
-    global _PALLAS_CALLS, _INTERPRETED_CALLS
     if interpret is None:
         interpret = default_interpret()
-    _PALLAS_CALLS += 1
-    _INTERPRETED_CALLS += bool(interpret)
+    count_pallas_call(interpret)
     t, bec = local_dst.shape
     nb, bn = deg.shape
     d = msgs.shape[-1]

@@ -42,6 +42,7 @@ def run_gnn(args) -> dict:
         use_cbs=not args.no_cbs,
         use_gp=not args.no_gp,
         max_epochs=args.epochs,
+        arch=args.arch,
         hidden_dim=args.hidden,
         batch_size=args.batch_size,
         fanouts=(args.fanout, args.fanout),
@@ -177,6 +178,10 @@ def main() -> int:
     g.add_argument("--no-cbs", action="store_true")
     g.add_argument("--no-gp", action="store_true")
     g.add_argument("--epochs", type=int, default=30)
+    g.add_argument("--arch", default="sage", choices=("sage", "gat"),
+                   help="GraphSAGE (mean aggregator) or GAT (PPI heads "
+                        "4, 4, 6; --hidden is its per-head width; needs "
+                        "--full-graph-train and runs no GP phase 1)")
     g.add_argument("--hidden", type=int, default=128)
     g.add_argument("--batch-size", type=int, default=256)
     g.add_argument("--fanout", type=int, default=10)

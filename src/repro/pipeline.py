@@ -61,7 +61,7 @@ Faithfulness notes:
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from typing import Any
 
 import jax
@@ -76,7 +76,7 @@ from .core.sampler import (CBSampler, build_device_epoch_sampler,
                            host_draw_count)
 from .engine import (EngineConfig, make_engine, stack_epoch_batches,
                      stack_pytrees)
-from .graph import (BENCHMARKS, GraphSAGE, NeighborSampler,
+from .graph import (BENCHMARKS, GAT, GraphSAGE, NeighborSampler,
                     build_partitioned_graph, make_benchmark)
 from .robustness import FaultPlan, InjectedCrash, RunCheckpointer
 from .train.metrics import F1Report, f1_scores
@@ -94,6 +94,12 @@ class EATConfig:
     use_gp: bool = True
     use_focal: bool = False
     max_epochs: int = 40
+    # the model: "sage" (GraphSAGE, mean aggregator) or "gat" (GAT with the
+    # paper's PPI heads (4, 4, 6)), which trains on the full-graph path only
+    # (full_graph_train) and runs no GP phase 1: personalization trains on
+    # sampled mini-batches, so use_gp is taken as False
+    arch: str = "sage"
+    # GraphSAGE's hidden width; GAT's per-head width (PPI: 256)
     hidden_dim: int = 128
     batch_size: int = 256
     fanouts: tuple[int, int] = (10, 10)
@@ -247,6 +253,7 @@ class EATResult:
     def summary(self) -> dict:
         return {
             "dataset": self.config.dataset,
+            "arch": self.config.arch,
             "method": self._label(),
             "parts": self.config.num_parts,
             "engine": self.engine_mode,
@@ -384,6 +391,20 @@ class _EpochPrefetcher:
 
 def run_eat_distgnn(cfg: EATConfig, verbose: bool = False,
                     fault_plan: FaultPlan | None = None) -> EATResult:
+    if cfg.arch not in ("sage", "gat"):
+        raise ValueError(f"unknown arch {cfg.arch!r} (expected 'sage' or "
+                         f"'gat')")
+    if cfg.arch == "gat" and not cfg.full_graph_train:
+        raise ValueError(
+            "arch='gat' has no sampled path: it trains on the full-graph "
+            "path only — set full_graph_train")
+    if cfg.arch == "gat" and cfg.use_gp:
+        # GP's phase 1 personalizes on sampled mini-batches: GAT trains
+        # phase 0 alone, to its own early stop
+        cfg = replace(cfg, use_gp=False)
+        if verbose:
+            print("[arch=gat] no GP phase 1 (sampled mini-batches): use_gp "
+                  "taken as False")
     if cfg.halo_cache and cfg.full_graph_train:
         raise ValueError(
             "halo_cache is an eval-forward optimisation; full_graph_train "
@@ -421,9 +442,15 @@ def run_eat_distgnn(cfg: EATConfig, verbose: bool = False,
 
     # ---------------- stacked shards + engine ------------------------------
     pg = build_partitioned_graph(graph, parts, n_parts)
-    model = GraphSAGE(feature_dim=graph.feature_dim, hidden_dim=cfg.hidden_dim,
-                      num_classes=graph.num_classes)
-    loss_fn = model.make_loss_fn(loss="focal" if cfg.use_focal else "ce")
+    if cfg.arch == "gat":
+        model = GAT(feature_dim=graph.feature_dim,
+                    num_classes=graph.num_classes, head_dim=cfg.hidden_dim)
+        loss_fn = None      # no sampled loss: the full-graph loss only
+    else:
+        model = GraphSAGE(feature_dim=graph.feature_dim,
+                          hidden_dim=cfg.hidden_dim,
+                          num_classes=graph.num_classes)
+        loss_fn = model.make_loss_fn(loss="focal" if cfg.use_focal else "ce")
     opt = AdamW(lr=cfg.lr, grad_clip=5.0)
     engine = make_engine(
         model, loss_fn, opt, pg,

@@ -105,6 +105,10 @@ class GNNServingEngine:
     def __init__(self, model, params, pg: PartitionedGraph, export: dict, *,
                  use_pallas_agg: bool = False,
                  hot_cache_rows: int = 256, planner_compact_after: int = 64):
+        if model.aggregation != "mean":
+            raise ValueError(
+                f"GNNServingEngine recomputes GraphSAGE's mean layer per "
+                f"row; it cannot serve {type(model).__name__}")
         if len(params.layers) != model.num_layers:
             raise ValueError("params depth != model.num_layers")
         self.model = model

@@ -1,4 +1,5 @@
-"""Compile-only checks of the ``segment_agg`` kernel for a TPU v5e.
+"""Compile-only checks of the ``segment_agg`` kernel and of GAT's
+``gat_agg`` and ``gat_sddmm`` kernels for a TPU v5e.
 
 Nothing runs: each test lowers the kernel for a described (not attached)
 v5e chip and compiles it with the TPU compiler, which refuses what
@@ -6,7 +7,11 @@ interpret mode accepts — block shapes off the (8, 128) tiling, unsupported
 in-kernel primitives, more VMEM than a kernel may use.  Shapes are the
 default job's: products-s at P=4 under EW partitioning, whose hub-heavy
 node blocks hold up to 13,952 in-edges (``BE``), with the 64-wide input
-features and the 128-wide hidden layer.
+features and the 128-wide hidden layer.  GAT's kernels are compiled at
+``gat-flickr``'s shapes: Flickr at P=4 under EW, 2,416 forward and 2,261
+transpose chunks, 476 node blocks, the 1,024-wide hidden layers (four
+heads of 256, two heads a call) and the last layer's six heads of 7
+classes.
 
 The topology is described inside a module-scoped fixture, never at import,
 so every pytest-xdist worker collects the same tests and only the worker
@@ -20,6 +25,8 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels.gat_agg import (gat_agg_blocks, gat_sddmm_blocks,
+                                   head_groups)
 from repro.kernels.segment_agg import (BEC, BN, build_edge_blocks,
                                        build_vjp_blocks, segment_agg_rows,
                                        segment_mean_op)
@@ -113,3 +120,73 @@ def test_segment_agg_rows_traced_row_base_compiles_for_v5e(one_chip,
         _spec(b.chunk_block, one_chip), _spec(b.deg, one_chip),
         row_base).compile()
     assert "tpu_custom_call" in c.as_text()
+
+
+# gat-flickr at P=4: forward / transpose chunks, node blocks
+GAT_T, GAT_T_T, GAT_NB = 2416, 2261, 476
+
+
+@pytest.mark.parametrize("heads,width", [(4, 1024), (6, 42)])
+def test_gat_kernels_compile_for_v5e(one_chip, heads, width):
+    """Each call of one layer's forward (and transpose: the same kernel on
+    fewer chunks) and of its SDDMM, at the layer's head groups."""
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    f = width // heads
+    for h0, h1 in head_groups(heads, width):
+        w = (h1 - h0) * f
+        for t in (GAT_T, GAT_T_T):
+            agg = jax.jit(lambda m, l, a, b: gat_agg_blocks(
+                m, l, a, b, GAT_NB, heads=h1 - h0, head0=h0,
+                interpret=False)).lower(
+                spec((t * BEC, w)), spec((t, BEC), jnp.int32),
+                spec((t, heads, BEC)), spec((t,), jnp.int32)).compile()
+            assert "tpu_custom_call" in agg.as_text()
+        sddmm = jax.jit(lambda g, m, l, b: gat_sddmm_blocks(
+            g, m, l, b, h1 - h0, interpret=False)).lower(
+            spec((GAT_NB * BN, w)), spec((GAT_T * BEC, w)),
+            spec((GAT_T, BEC), jnp.int32), spec((GAT_T,), jnp.int32)
+            ).compile()
+        assert "tpu_custom_call" in sddmm.as_text()
+
+
+def test_gat_train_step_runs_the_calls_the_benchmark_counts(one_chip):
+    """A GAT training step at the published heads and widths (a small
+    graph), compiled for the chip, holds the kernel calls that
+    ``perfbench/kernels/gat_agg.py`` and ``gat_sddmm.py`` count for one
+    step: per hidden layer two calls of two heads for each of the forward,
+    its recomputation in the backward pass and the transpose, and for the
+    last layer the forward and the transpose (its recomputation is
+    dropped); one SDDMM call a head group."""
+    import re
+
+    from repro.graph import GAT
+    from repro.kernels.gat_agg import attention_agg_op, build_gat_blocks
+
+    n, d = 1024, 64
+    rng = np.random.default_rng(0)
+    blocks = build_gat_blocks(rng.integers(0, n, 8000),
+                              np.sort(rng.integers(0, n, 8000)), n, n)
+    model = GAT(feature_dim=d, num_classes=7)
+
+    def loss(params, x, blocks):
+        agg = lambda z, ss, sd, _: attention_agg_op(
+            z, ss, sd, blocks, num_rows=n, interpret=False)
+        h = x
+        for i, lp in enumerate(params.layers):
+            h = model.layer(lp, h, None, agg, i, i == model.num_layers - 1)
+        return (h * h).sum()
+
+    spec = lambda a: jax.ShapeDtypeStruct(np.shape(a), jnp.asarray(a).dtype,
+                                          sharding=one_chip)
+    text = jax.jit(jax.value_and_grad(loss)).lower(
+        jax.tree.map(spec, model.init(0)), spec(np.zeros((n, d), np.float32)),
+        jax.tree.map(spec, blocks)).compile().as_text()
+    calls = [line for line in text.split("\n")
+             if 'custom_call_target="tpu_custom_call"' in line]
+    count = lambda name: sum(
+        re.match(rf"\s*%\w*{name}\w*(\.\d+)? = ", line) is not None
+        for line in calls)
+    assert count("gat_agg") == 2 * 3 * 2 + 2 == 14
+    assert count("gat_sddmm") == 2 * 2 + 1 == 5
