@@ -86,6 +86,7 @@ from ..graph.distributed import (HALO_COMPRESS_MODES, PartitionedGraph,
                                  make_export_forward,
                                  make_overlap_forward, make_pallas_attn_agg,
                                  make_pallas_mean_agg, make_pallas_split_agg,
+                                 make_pallas_trim_agg,
                                  make_ref_attn_agg, make_ref_mean_agg,
                                  make_ref_split_agg, wire_row_bytes)
 from ..graph.featstore import (assemble_features, check_feat_budget,
@@ -96,6 +97,7 @@ from .stacking import (build_stacked_feat_store, build_stacked_halo_cache,
                        build_stacked_halo_residual,
                        build_stacked_gat_blocks,
                        build_stacked_split_vjp_blocks,
+                       build_stacked_trim_blocks,
                        build_stacked_vjp_blocks, stack_pytrees)
 
 __all__ = ["AXIS", "EngineConfig", "SPMDEngine", "stack_epoch_batches"]
@@ -150,6 +152,13 @@ class EngineConfig:
     # build a configuration whose closed-form peak device feature bytes
     # exceed it (FeatureBudgetError) instead of OOMing mid-epoch
     feat_budget_mb: float = 0.0
+
+
+def _as_blk(d: dict, dtype) -> dict:
+    """One nested pytree per aggregation call site: int arrays stay int32,
+    float structure follows the feature dtype."""
+    return {k: jnp.asarray(v, dtype) if v.dtype == np.float32
+            else jnp.asarray(v) for k, v in d.items()}
 
 
 def _resolve_mode(mode: str, num_parts: int) -> str:
@@ -297,21 +306,14 @@ class SPMDEngine:
                           context=f"mode={self.mode}")
         # GAT's attention: the transpose reads alpha through a slot map
         attention = model.aggregation == "attention"
-
-        def _as_blk(d: dict) -> dict:
-            # one nested pytree per segment_mean_op call site: int arrays
-            # stay int32, float structure follows the feature dtype
-            return {k: jnp.asarray(v, f) if v.dtype == np.float32
-                    else jnp.asarray(v) for k, v in d.items()}
-
         if config.overlap_halo:
             # split forward state: the per-partition interior row count plus
             # ONE aggregation backend's structures (the other is never read)
             shards["n_int"] = jnp.asarray(pg.n_int, jnp.int32)
             if config.use_pallas_agg:
                 bi, bb = build_stacked_split_vjp_blocks(pg)
-                shards["blk_int"] = _as_blk(bi)
-                shards["blk_bnd"] = _as_blk(bb)
+                shards["blk_int"] = _as_blk(bi, f)
+                shards["blk_bnd"] = _as_blk(bb, f)
             else:
                 shards.update({
                     "int_src": jnp.asarray(pg.int_src),
@@ -329,7 +331,15 @@ class SPMDEngine:
             if config.use_pallas_agg:
                 shards["blk"] = _as_blk(
                     build_stacked_gat_blocks(pg) if attention
-                    else build_stacked_vjp_blocks(pg))
+                    else build_stacked_vjp_blocks(pg), f)
+        # edge slots of one whole-graph aggregation, every partition: the
+        # chunk layouts' on the Pallas backend, the padded edge lists' on
+        # the jnp one (the second number of last_eval_agg_slots)
+        blk = [shards[k] for k in ("blk", "blk_int", "blk_bnd") if k in shards]
+        self._agg_slots = (sum(int(b["src"].size) for b in blk) if blk else
+                           sum(int(shards[k].size) for k in
+                               ("edge_src", "int_src", "bnd_src")
+                               if k in shards))
         self._mesh = None
         if self.mode == "spmd":
             from ..launch.mesh import make_partition_mesh
@@ -341,6 +351,7 @@ class SPMDEngine:
                               "test": pg.test_mask}}
         where = (NamedSharding(self._mesh, P(AXIS)) if self._mesh is not None
                  else None)
+        self._where = where
         self._resident = jax.tree.map(
             lambda x: jax.device_put(jnp.asarray(x), where), resident)
 
@@ -377,6 +388,21 @@ class SPMDEngine:
                     model, meta, axis_name=AXIS, agg=agg,
                     compress=config.halo_compress,
                     ring_chunks=config.ring_chunks)
+        # the evaluation's last mean layer over the evaluated split's
+        # in-edges only (_eval_trim); the other spellings keep the full
+        # layout, each with its own aggregation
+        self._fwd_trim = None
+        self._trim: dict = {}
+        self.last_eval_agg_slots = (0, 0)   # (slots the latest evaluate's
+                                            # last layer aggregated, slots
+                                            # of the full layout)
+        if (model.aggregation == "mean" and config.use_pallas_agg
+                and not (config.overlap_halo or config.halo_cache
+                         or config.halo_compress != "none"
+                         or config.feat_groups)):
+            self._fwd_trim = make_distributed_forward(
+                model, meta, axis_name=AXIS, agg=self._mean_agg,
+                last_agg=make_pallas_trim_agg(pg.max_nodes))
         if self.halo_compress != "none":
             self._halo_residual = jax.tree.map(
                 lambda x: jnp.asarray(x, f),
@@ -750,17 +776,55 @@ class SPMDEngine:
 
     # ------------------------------------------------- stacked (vmap) mode
     def _eval_stacked(self, params, split: str, per_partition_params: bool,
-                      fs=()):
+                      fs=(), trim=None):
+        fwd, shards = self._eval_fwd(trim)
+
         def one(prm, shard, *cold):
-            return self.fwd(prm, self._featurize(shard, *cold)
-                            if cold else shard)
+            return fwd(prm, self._featurize(shard, *cold) if cold else shard)
 
         in_axes = (0 if per_partition_params else None, 0) + (0,) * len(fs)
         logits = jax.vmap(one, axis_name=AXIS, in_axes=in_axes)(
-            params, self.shards, *fs)                # (P, maxN, C)
-        preds = jnp.argmax(logits, axis=-1)
+            params, shards, *fs)                     # (P, maxN, C)
+        preds = self._split_preds(logits, self.masks[split], trim)
         micro = jax.vmap(self._micro_of)(preds, self.labels, self.masks[split])
         return micro, preds
+
+    def _eval_fwd(self, trim):
+        """The eval's forward and the shards it reads: with a trimmed
+        structure, the last layer aggregates over it (``blk_trim``)."""
+        if trim is None:
+            return self.fwd, self.shards
+        return self._fwd_trim, {**self.shards, "blk_trim": trim}
+
+    @staticmethod
+    def _split_preds(logits, mask, trim):
+        """Each row's class; -1 outside the split where the last layer ran
+        over the split's in-edges only."""
+        preds = jnp.argmax(logits, axis=-1)
+        return preds if trim is None else jnp.where(mask, preds, -1)
+
+    def _eval_trim(self, split: str):
+        """``(structure, slots)`` of ``split``'s last eval layer: the
+        resident (P, ...) structure over the split rows' in-edges
+        (``build_stacked_trim_blocks``), built on the split's first
+        evaluate, and the chunk slots it runs; ``(None, full slots)`` where
+        the full layout is kept — a spelling with its own aggregation, or
+        a split whose trimmed layout has no fewer chunks."""
+        if self._fwd_trim is None:
+            return None, self._agg_slots
+        if split not in self._trim:
+            sh = self.shards
+            b = build_stacked_trim_blocks(
+                np.asarray(sh["edge_src"]), np.asarray(sh["edge_dst"]),
+                np.asarray(sh["edge_mask"]), np.asarray(self.masks[split]),
+                np.asarray(sh["blk"]["deg"]))
+            if b["src"].shape[1] < sh["blk"]["src"].shape[1]:
+                self._trim[split] = (jax.device_put(
+                    _as_blk(b, self.config.dtype), self._where),
+                    int(b["src"].size))
+            else:
+                self._trim[split] = (None, self._agg_slots)
+        return self._trim[split]
 
     def _grad_reduce_stacked(self):
         """Stacked-mode gradient reducer for the configured grad_compress
@@ -1195,13 +1259,15 @@ class SPMDEngine:
                   ds.logp, ds.train_idx, ds.k, *fs)
 
     def _eval_spmd(self, params, split: str, per_partition_params: bool,
-                   fs=()):
+                   fs=(), trim=None):
+        fwd, shards = self._eval_fwd(trim)
+
         def shard_fn(prm, shard_s, labels_s, mask_s, *cold_s):
             p = jax.tree.map(lambda x: x[0], prm) if per_partition_params else prm
             sh = jax.tree.map(lambda x: x[0], shard_s)
             if cold_s:
                 sh = self._featurize(sh, cold_s[0][0])
-            preds = jnp.argmax(self.fwd(p, sh), axis=-1)
+            preds = self._split_preds(fwd(p, sh), mask_s[0], trim)
             micro = self._micro_of(preds, labels_s[0], mask_s[0])
             return micro[None], preds[None]
 
@@ -1210,7 +1276,7 @@ class SPMDEngine:
             in_specs=(P(AXIS) if per_partition_params else P(),
                       P(AXIS), P(AXIS), P(AXIS)) + (P(AXIS),) * len(fs),
             out_specs=(P(AXIS), P(AXIS)), check_vma=False)
-        return fn(params, self.shards, self.labels, self.masks[split], *fs)
+        return fn(params, shards, self.labels, self.masks[split], *fs)
 
     # ------------------------------------------------------- public surface
     # Epoch methods return a trailing ``device_seconds``: wall time of the
@@ -1440,6 +1506,14 @@ class SPMDEngine:
 
     def evaluate(self, params, split: str = "test",
                  per_partition_params: bool = True):
+        """``(micro (P,), preds (P, maxN))`` of ``split``: each partition's
+        micro-F1 over its ``split`` rows, and each row's class.  Read
+        ``preds`` on the split's rows only: where the last layer runs over
+        the split's in-edges alone (GraphSAGE's mean on the plain Pallas
+        forward, :meth:`_eval_trim`), every other row reads -1.  Sets
+        ``last_eval_agg_slots``."""
+        trim, slots = self._eval_trim(split)
+        self.last_eval_agg_slots = (slots, self._agg_slots)
         if self.config.feat_groups:
             return self._evaluate_streamed(params, split,
                                            per_partition_params)
@@ -1487,18 +1561,22 @@ class SPMDEngine:
             self.last_halo_exchange_bytes = (self.model.num_layers
                                              * self.halo_wire_bytes_per_layer)
             return micro, preds
-        if self.mode == "spmd":
-            impl = lambda prm, *c: self._eval_spmd(
-                prm, split, per_partition_params, fs=c)
+        impl_of = (self._eval_spmd if self.mode == "spmd"
+                   else self._eval_stacked)
+        if trim is None:
+            impl = lambda prm, *c: impl_of(prm, split, per_partition_params,
+                                           fs=c)
+            args = (params, *fs)
         else:
-            impl = lambda prm, *c: self._eval_stacked(
-                prm, split, per_partition_params, fs=c)
+            impl = lambda prm, t, *c: impl_of(prm, split, per_partition_params,
+                                              fs=c, trim=t)
+            args = (params, trim, *fs)
         fn = self._compiled(f"eval-{split}-{per_partition_params}", impl,
-                            params, *fs)
+                            *args)
         # execution time of the compiled eval (AOT compile excluded), so the
         # pipeline can compare host-path epochs, whose eval is a separate
         # call, against the fused async epoch whose timing includes eval
-        out, self.last_eval_seconds = self._timed(fn, params, *fs)
+        out, self.last_eval_seconds = self._timed(fn, *args)
         return out
 
     def _evaluate_streamed(self, params, split: str,

@@ -18,11 +18,13 @@ import numpy as np
 from ..graph.distributed import PartitionedGraph
 from ..graph.featstore import PartitionFeatStore, build_partition_feat_store
 from ..kernels.segment_agg import (BEC, BN, build_edge_blocks,
+                                   build_edge_blocks_from_edges,
                                    build_transpose_blocks)
 
 __all__ = ["StackedBlocks", "build_stacked_vjp_blocks",
            "build_stacked_gat_blocks",
-           "build_stacked_split_vjp_blocks", "build_stacked_feat_store",
+           "build_stacked_split_vjp_blocks", "build_stacked_trim_blocks",
+           "build_stacked_feat_store",
            "build_stacked_halo_cache", "build_stacked_halo_residual",
            "stack_pytrees"]
 
@@ -198,6 +200,45 @@ def build_stacked_gat_blocks(pg: PartitionedGraph) -> dict:
                            pad=out["src"][0].size)
         for p in range(pg.num_parts)])
     return out
+
+
+def build_stacked_trim_blocks(edge_src: np.ndarray, edge_dst: np.ndarray,
+                              edge_mask: np.ndarray, rows: np.ndarray,
+                              deg: np.ndarray, bn: int = BN,
+                              bec: int = BEC) -> dict:
+    """Forward block structure over only the in-edges of the rows in
+    ``rows`` (P, maxN) bool — the aggregation of a last layer whose output
+    is read on those rows alone (the evaluated split).
+
+    Blocks are COMPACT: only the node blocks that hold a ``rows`` row get
+    chunks, renumbered ``0..nbk-1`` in ascending order, each owning at
+    least one chunk, and are padded across partitions by
+    :func:`_stack_blocks` (a partition with no such row gets all-pad
+    chunks only).  A kept row keeps every in-edge, and ``deg`` (P, nb, bn)
+    is the full layout's degree, sliced per kept block, so its mean is the
+    full layout's.  ``out`` (P, nbk) maps each compact block to its node
+    block; a pad block maps to ``nb``, one past the last node block."""
+    P = edge_src.shape[0]
+    nb = deg.shape[1]
+    per_part, kept = [], []
+    for p in range(P):
+        blocks = np.unique(np.flatnonzero(rows[p]) // bn)
+        compact = np.full(nb, -1, np.int64)
+        compact[blocks] = np.arange(len(blocks))
+        dst = edge_dst[p].astype(np.int64)
+        keep = (edge_mask[p] > 0) & rows[p][dst]
+        cdst = compact[dst[keep] // bn] * bn + dst[keep] % bn
+        per_part.append(build_edge_blocks_from_edges(
+            edge_src[p][keep], cdst, len(blocks) * bn, bn=bn, bec=bec))
+        kept.append(blocks)
+    s = _stack_blocks(per_part, P, bn)
+    out = np.full((P, s.num_blocks), nb, np.int32)
+    deg_t = np.ones((P, s.num_blocks, bn), np.float32)
+    for p, blocks in enumerate(kept):
+        out[p, :len(blocks)] = blocks
+        deg_t[p, :len(blocks)] = deg[p, blocks]
+    return {"src": s.src, "dst": s.local_dst, "mask": s.mask,
+            "blk": s.chunk_block, "deg": deg_t, "out": out}
 
 
 def build_stacked_split_vjp_blocks(pg: PartitionedGraph, bn: int = BN,

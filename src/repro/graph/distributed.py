@@ -48,7 +48,7 @@ __all__ = ["PartitionedGraph", "build_partitioned_graph", "make_distributed_forw
            "halo_refresh_plan", "RecomputePlanner",
            "HALO_COMPRESS_MODES", "quantize_rows", "dequantize_rows",
            "wire_row_bytes",
-           "make_ref_mean_agg", "make_pallas_mean_agg",
+           "make_ref_mean_agg", "make_pallas_mean_agg", "make_pallas_trim_agg",
            "make_ref_split_agg", "make_pallas_split_agg",
            "make_ref_attn_agg", "make_pallas_attn_agg"]
 
@@ -519,6 +519,29 @@ def make_pallas_mean_agg(max_nodes: int):
     return mean_agg
 
 
+def make_pallas_trim_agg(max_nodes: int):
+    """Pallas mean aggregation over ``shard["blk_trim"]``
+    (``engine.stacking.build_stacked_trim_blocks``): the in-edges of the
+    rows one evaluated split reads, in compact node blocks.  The kernel's
+    ``(nbk * BN, D)`` output is scattered block by block into a ZERO
+    ``(max_nodes, D)`` array through the block map ``out``; pad blocks map
+    past the last node block and are dropped, and rows of no kept block
+    read 0.  Forward only: the evaluation's last layer."""
+    from ..kernels.segment_agg import segment_agg_blocks
+
+    def trim_agg(h, shard):
+        b = shard["blk_trim"]
+        nb, bn = b["out"].shape[0], b["deg"].shape[1]
+        d = h.shape[-1]
+        out = segment_agg_blocks(h[b["src"].reshape(-1)], b["dst"], b["mask"],
+                                 b["blk"], b["deg"]).reshape(nb, bn, d)
+        placed = jnp.zeros((-(-max_nodes // bn), bn, d), out.dtype)
+        placed = placed.at[b["out"]].set(out, mode="drop")
+        return placed.reshape(-1, d)[:max_nodes].astype(h.dtype)
+
+    return trim_agg
+
+
 def make_pallas_split_agg(own_cap: int):
     """Pallas interior/boundary aggregation pair for the overlapped forward.
 
@@ -589,7 +612,8 @@ def _mean_models_only(model, spelling: str) -> None:
 
 def make_distributed_forward(model, pg_meta: dict,
                              axis_name: str = "data", agg=None,
-                             compress: str = "none", ring_chunks: int = 0):
+                             compress: str = "none", ring_chunks: int = 0,
+                             last_agg=None):
     """Build the per-shard n-layer SYNCHRONOUS forward with halo exchange.
 
     The forward owns the loop and each layer's halo exchange; the model
@@ -624,6 +648,10 @@ def make_distributed_forward(model, pg_meta: dict,
     compressed spelling writes GraphSAGE's layer out and refuses any other
     model.
 
+    ``last_agg`` replaces ``agg`` in the last layer only (the evaluation's
+    :func:`make_pallas_trim_agg`, whose output is valid on the evaluated
+    split's rows alone); ``None`` keeps ``agg`` in every layer.
+
     Every layer's exchange fully serialises before any aggregation — the
     baseline :func:`make_overlap_forward` is benchmarked against.
     """
@@ -631,6 +659,7 @@ def make_distributed_forward(model, pg_meta: dict,
     if agg is None:
         agg = (make_ref_attn_agg if model.aggregation == "attention"
                else make_ref_mean_agg)(max_nodes)
+    last_agg = agg if last_agg is None else last_agg
 
     if compress == "none":
         def fwd(params, shard: dict) -> jnp.ndarray:
@@ -639,7 +668,8 @@ def make_distributed_forward(model, pg_meta: dict,
             for i, lp in enumerate(params.layers):
                 h = _halo_exchange(h, shard["send_idx"], shard["send_mask"],
                                    shard["recv_pos"], axis_name)
-                h = model.layer(lp, h, shard, agg, i, i == last)
+                h = model.layer(lp, h, shard, last_agg if i == last else agg,
+                                i, i == last)
             return h
 
         return fwd

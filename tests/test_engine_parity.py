@@ -18,7 +18,9 @@
    too: its only collectives are data movement, no pmean), with the fused
    eval bitwise equal to a standalone evaluate().
 4. shard_map mode: with 4 forced host devices the mesh engine matches the
-   stacked engine to collective-reduction rounding (<= a few f32 ulps).
+   stacked engine to collective-reduction rounding (<= a few f32 ulps), and
+   its eval with the last layer over the split's in-edges only gives each
+   split row the untrimmed forward's class.
 5. Pallas on the hot path: the distributed eval forward demonstrably stages
    ``segment_agg`` (trace-time call counter) and agrees with the jnp
    segment-op reference.
@@ -53,6 +55,7 @@ subprocess enables the persistent compilation cache under ``.jax_cache/``
 so reruns skip compilation entirely.
 """
 import json
+import os
 import subprocess
 import sys
 
@@ -842,6 +845,51 @@ def test_spmd_shard_map_matches_stacked():
     assert d["test_micro"] <= 5e-3 and d["test_pred_mismatch"] <= 3, d
 
 
+SPMD_TRIM_SCRIPT = (
+    "import os, sys\n"
+    "os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=4'\n"
+    f"sys.path.insert(0, {os.path.dirname(os.path.abspath(__file__))!r})\n"
+    + CACHE_PRELUDE
+    + r"""
+import json
+from _trim_case import build_trim_graph, trim_gaps
+from repro.core import GPHyperParams
+from repro.engine import EngineConfig, SPMDEngine
+from repro.graph import GraphSAGE
+from repro.train.optim import AdamW
+g, pg, _ = build_trim_graph()
+model = GraphSAGE(feature_dim=g.feature_dim, hidden_dim=16,
+                  num_classes=g.num_classes)
+eng = SPMDEngine(model, model.make_loss_fn(), AdamW(), pg, GPHyperParams(),
+                 EngineConfig(mode="spmd", use_pallas_agg=True))
+assert eng.mode == "spmd", eng.mode
+params = jax.tree.map(lambda x: 4.0 * x, model.init(0))
+d = {s: trim_gaps(eng, params, s) for s in ("val", "test")}
+d["slots"] = eng.last_eval_agg_slots
+print("RESULTS", json.dumps(d))
+"""
+)
+
+
+def test_spmd_trimmed_eval_equals_the_untrimmed_forward():
+    """``_eval_spmd`` with the last layer over the split's in-edges only
+    (tests/test_eval_trim.py's graph and checks), one partition a device
+    on a 4-device mesh: each split row's class and logits as the untrimmed
+    forward's, -1 on every other row."""
+    res = subprocess.run([sys.executable, "-c", SPMD_TRIM_SCRIPT],
+                         capture_output=True, text=True, timeout=900,
+                         env=SUBPROC_ENV)
+    assert res.returncode == 0, res.stderr[-3000:]
+    line = [l for l in res.stdout.splitlines() if l.startswith("RESULTS")][0]
+    d = json.loads(line[len("RESULTS "):])
+    for split in ("val", "test"):
+        gaps = d[split]
+        assert gaps["logit_rel"] <= 1e-5, d
+        assert gaps["class_diff"] == 0 and gaps["eval_gap"] == 0.0, d
+        assert gaps["not_minus_one"] == 0, d
+    assert d["slots"][0] < d["slots"][1], d
+
+
 SPMD_FP64_ASYNC_SCRIPT = (
     "import os\n"
     "os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=4'\n"
@@ -978,8 +1026,12 @@ def test_distributed_forward_calls_pallas_segment_agg():
     micro_ref, preds_ref = eng_ref.evaluate(params, "val")
     np.testing.assert_allclose(np.asarray(micro_pal), np.asarray(micro_ref),
                                atol=1e-6)
-    agree = (np.asarray(preds_pal) == np.asarray(preds_ref)).mean()
+    # the Pallas eval's last layer runs over the val rows' in-edges only:
+    # its other rows read -1
+    val = np.asarray(eng_pal.masks["val"])
+    agree = (np.asarray(preds_pal)[val] == np.asarray(preds_ref)[val]).mean()
     assert agree > 0.999, f"pallas/ref argmax agreement only {agree}"
+    assert (np.asarray(preds_pal)[~val] == -1).all()
 
 
 def test_fullgraph_train_through_pallas_kernel():

@@ -11,7 +11,9 @@ features and the 128-wide hidden layer.  GAT's kernels are compiled at
 ``gat-flickr``'s shapes: Flickr at P=4 under EW, 2,416 forward and 2,261
 transpose chunks, 476 node blocks, the 1,024-wide hidden layers (four
 heads of 256, two heads a call) and the last layer's six heads of 7
-classes.
+classes.  The evaluation's trimmed last layer (the kernel over a split's
+in-edges in compact node blocks, and the scatter of its blocks into place)
+is compiled over the same hub-heavy edges.
 
 The topology is described inside a module-scoped fixture, never at import,
 so every pytest-xdist worker collects the same tests and only the worker
@@ -120,6 +122,43 @@ def test_segment_agg_rows_traced_row_base_compiles_for_v5e(one_chip,
         _spec(b.chunk_block, one_chip), _spec(b.deg, one_chip),
         row_base).compile()
     assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("d", [128, 256])
+def test_trimmed_eval_layer_compiles_for_v5e(one_chip, hub_edges, d,
+                                             monkeypatch):
+    """The evaluation's last layer over a 2% split's in-edges: compact
+    renumbered node blocks, stacked for two partitions of unequal block
+    counts (one holds the hub block), the kernel vmapped over them, and
+    the block-map scatter of its output into a zero array."""
+    from repro.engine.stacking import build_stacked_trim_blocks
+    from repro.graph.distributed import make_pallas_trim_agg
+    from repro.kernels import segment_agg
+
+    src, dst = hub_edges
+    rng = np.random.default_rng(1)
+    e = np.full((2, src.size), N_ROWS - 1, np.int32)
+    e_src, e_dst, e_mask = e.copy(), e.copy(), np.zeros(e.shape, np.float32)
+    rows = np.zeros((2, N_ROWS), bool)
+    for p, frac in enumerate((0.02, 0.005)):
+        e_src[p], e_dst[p], e_mask[p] = src, dst, 1.0
+        pick = rng.choice(N_ROWS - 1, int(frac * N_ROWS), replace=False)
+        rows[p, pick] = True
+    rows[0, 5] = True                        # a hub-block row
+    deg = np.maximum(np.bincount(dst, minlength=N_ROWS), 1).astype(np.float32)
+    trim = build_stacked_trim_blocks(e_src, e_dst, e_mask, rows,
+                                     np.stack([deg.reshape(-1, BN)] * 2))
+    nb = N_ROWS // BN
+    kept = (trim["out"] < nb).sum(1)
+    assert kept[0] != kept[1] and trim["out"].shape[1] < nb
+    # the TPU lowering of the kernel, though the backend here is the CPU
+    monkeypatch.setattr(segment_agg, "default_interpret", lambda: False)
+    agg = make_pallas_trim_agg(N_ROWS)
+    h = jax.ShapeDtypeStruct((2, N_ROWS, d), jnp.float32, sharding=one_chip)
+    c = jax.jit(jax.vmap(lambda x, t: agg(x, {"blk_trim": t}))).lower(
+        h, {k: _spec(v, one_chip) for k, v in trim.items()}).compile()
+    text = c.as_text()
+    assert "tpu_custom_call" in text and "scatter" in text
 
 
 # gat-flickr at P=4: forward / transpose chunks, node blocks
